@@ -17,6 +17,8 @@ from repro.kernels.phi_detect.ops import edge_density
 from repro.kernels.scrub.ops import pack_rects, scrub_images
 from repro.launch import hw
 
+V5E = hw.peaks(hw.V5E)  # the bound columns model a v5e chip
+
 
 def _time(fn, n=3):
     fn()  # warm/compile
@@ -41,13 +43,13 @@ def main() -> list[str]:
     # scrub reads+writes each pixel once -> v5e bound = HBM/2
     lines.append(
         f"scrub_kernel,{t_k*1e6:.0f},host_MBps={nbytes/t_k/1e6:.0f};numpy_MBps={nbytes/t_n/1e6:.0f};"
-        f"v5e_bound_GBps={hw.HBM_BW/2/1e9:.0f}"
+        f"v5e_bound_GBps={V5E.hbm_bw/2/1e9:.0f}"
     )
 
     t_p = _time(lambda: np.asarray(edge_density(jimgs)))
     lines.append(
         f"phi_detect_kernel,{t_p*1e6:.0f},host_MBps={nbytes/t_p/1e6:.0f};"
-        f"v5e_bound_GBps={hw.HBM_BW/1e9:.0f}"
+        f"v5e_bound_GBps={V5E.hbm_bw/1e9:.0f}"
     )
 
     t_j = _time(lambda: np.asarray(jls_residuals(imgs)))
@@ -55,7 +57,7 @@ def main() -> list[str]:
     # jls reads u16, writes s32 residuals -> 1:3 traffic
     lines.append(
         f"jls_kernel,{t_j*1e6:.0f},host_MBps={nbytes/t_j/1e6:.0f};numpy_MBps={nbytes/t_c/1e6:.0f};"
-        f"v5e_bound_GBps={hw.HBM_BW/3/1e9:.0f}"
+        f"v5e_bound_GBps={V5E.hbm_bw/3/1e9:.0f}"
     )
 
     # fused scrub+JLS: one HBM pass for both bandwidth-bound stages.
@@ -70,8 +72,8 @@ def main() -> list[str]:
     lines.append(
         f"fused_scrub_jls_kernel,{t_f*1e6:.0f},host_MBps={nbytes/t_f/1e6:.0f};"
         f"staged_MBps={nbytes/t_s/1e6:.0f};traffic_ratio={fused_bpp/staged_bpp:.2f};"
-        f"v5e_bound_GBps={hw.HBM_BW*item/fused_bpp/1e9:.0f};"
-        f"staged_pair_bound_GBps={hw.HBM_BW*item/staged_bpp/1e9:.0f}"
+        f"v5e_bound_GBps={V5E.hbm_bw*item/fused_bpp/1e9:.0f};"
+        f"staged_pair_bound_GBps={V5E.hbm_bw*item/staged_bpp/1e9:.0f}"
     )
     return lines
 

@@ -26,6 +26,8 @@ from pathlib import Path
 
 from repro.launch import hw
 
+V5E = hw.peaks(hw.V5E)  # the device terms model a v5e chip
+
 BENCH_JSON = Path(__file__).resolve().parents[1] / "BENCH_fused.json"
 OUT_MD = Path(__file__).resolve().parents[1] / "experiments" / "roofline.md"
 
@@ -59,7 +61,7 @@ def analyze(row: dict) -> dict:
     r["hidden_s_per_gb"] = d * 1e9
     # device roofline: the fused scrub+residual+plan kernel is HBM-bound —
     # read itemsize bytes/pixel, write int32 residual + int32 len/rem words
-    dev_gbps = row.get("tpu_fused_gb_s") or (hw.HBM_BW / 2 / 1e9)
+    dev_gbps = row.get("tpu_fused_gb_s") or (V5E.hbm_bw / 2 / 1e9)
     r["device_roofline_gb_s"] = dev_gbps
     r["cores_per_chip"] = dev_gbps * 1e9 / batched
     r["bound"] = "host" if d <= h else "device"
@@ -70,8 +72,8 @@ def to_markdown(rows: list[dict]) -> str:
     lines = [
         "# Host/device boundary roofline (pipelined de-id path)",
         "",
-        f"Device terms use v5e constants: HBM {hw.HBM_BW / 1e9:.0f} GB/s, "
-        f"peak {hw.PEAK_FLOPS_BF16 / 1e12:.0f} TFLOP/s bf16. Host terms are "
+        f"Device terms use v5e constants: HBM {V5E.hbm_bw / 1e9:.0f} GB/s, "
+        f"peak {V5E.flops_bf16 / 1e12:.0f} TFLOP/s bf16. Host terms are "
         "measured single-core throughput from BENCH_fused.json.",
         "",
         "| modality | batched MB/s | serial MB/s | speedup | ideal overlap | "

@@ -7,6 +7,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     from benchmarks import (
         auditbench,
         autoscale,

@@ -32,6 +32,7 @@ PAPER_ROWS = {
 }
 
 FLEET_CORES = 8 * 32
+V5E = hw.peaks(hw.V5E)  # the tpu_* columns model a v5e chip
 PARALLEL_EFF = 0.85
 
 
@@ -122,9 +123,9 @@ def run(n_studies: int = 6, recompress: bool = True, rounds: int = 3) -> list[Ro
                 modeled_cost=cost,
                 paper_gb_s=paper["agg_gbps"],
                 paper_cost=paper["cost"],
-                tpu_scrub_gb_s=hw.HBM_BW / 2 / 1e9,  # read+write each pixel once
+                tpu_scrub_gb_s=V5E.hbm_bw / 2 / 1e9,  # read+write each pixel once
                 # fused single pass: read dtype + write int32 residuals
-                tpu_fused_gb_s=hw.HBM_BW * itemsize / (itemsize + 4) / 1e9,
+                tpu_fused_gb_s=V5E.hbm_bw * itemsize / (itemsize + 4) / 1e9,
                 serial_mb_s_core=nbytes / dt_serial / 1e6,
                 batched_instances=stats1[0] - stats0[0],
                 kernel_dispatches=stats1[1] - stats0[1],

@@ -42,6 +42,16 @@ from repro.utils.logging import get_logger
 log = get_logger("catalog")
 
 
+def _scan_path(mode: str) -> str:
+    """What evaluates a ``match_mask`` scan in ``mode``: the numpy oracle, or
+    the Pallas combine kernel — compiled, or interpreted on the CPU backend."""
+    if mode == "oracle":
+        return "oracle"
+    from repro.kernels import on_cpu
+
+    return "pallas_interpret" if on_cpu() else "pallas"
+
+
 @dataclass
 class CatalogStats:
     rows: int = 0
@@ -265,6 +275,7 @@ class StudyCatalog:
         with self.tracer.span("catalog.select", mode=mode) as _scan_span:
             mask, n_scanned, n_pruned = self.match_mask(pred, mode=mode, prune=prune)
             _scan_span.set(
+                path=_scan_path(mode),
                 blocks_scanned=n_scanned,
                 blocks_pruned=n_pruned,
                 matched=int(mask.sum()),

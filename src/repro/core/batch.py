@@ -221,9 +221,9 @@ class BatchedDeidExecutor:
 
     def _resolve_use_kernel(self) -> bool:
         if self.use_kernel is None:
-            import jax
+            from repro.kernels import on_cpu
 
-            self.use_kernel = jax.default_backend() != "cpu"
+            self.use_kernel = not on_cpu()
         return self.use_kernel
 
     def _use_device_entropy(self, use_kernel: bool) -> bool:
@@ -451,13 +451,11 @@ class BatchedDeidExecutor:
                 from repro.kernels.jls import entropy
 
                 rs = np.asarray(st.rs)  # device sync point
-                ks = np.array(
-                    [
-                        codec._rice_k_from_sum(int(rs[j].sum()), H * W)
-                        for j in range(len(chunk))
-                    ],
-                    np.int32,
-                )
+                # one k per padded frame: padding frames get k=0 and are
+                # never packed, but the kernel reads a k for every frame
+                ks = np.zeros(st.u.shape[0], np.int32)
+                for j in range(len(chunk)):
+                    ks[j] = codec._rice_k_from_sum(int(rs[j].sum()), H * W)
                 lens_d, rem_d = entropy.rice_len_rem(
                     st.u, ks, bh=self.bh, interpret=self.interpret
                 )
@@ -473,7 +471,7 @@ class BatchedDeidExecutor:
                         for j in range(len(chunk))
                     ]
                 )
-                kparams = [int(k) for k in ks]
+                kparams = [int(k) for k in ks[: len(chunk)]]
             elif st.kind == "device_res":
                 res = np.asarray(st.res)  # device sync point
                 st.jobs = self._submit_jobs(

@@ -25,14 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 top-level API
-    _shard_map = jax.shard_map
-    _REPLICATION_KW = "check_vma"
-except AttributeError:  # older jax: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _REPLICATION_KW = "check_rep"
-
 from repro.dicom.dataset import DicomDataset
 from repro.dicom.devices import Rect
 from repro.kernels.scrub.ops import pack_rects, scrub_images
@@ -67,14 +59,14 @@ class ScrubFarm:
                 # per-device shard: batch slice, full images; kernel does tiles
                 return scrub_images(images, rects)
 
-            fn = _shard_map(
+            fn = jax.shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=(P("workers"), P("workers")),
                 out_specs=P("workers"),
                 # pallas_call's out_shape carries no varying-mesh-axes info;
                 # the farm is embarrassingly parallel so nothing to check
-                **{_REPLICATION_KW: False},
+                check_vma=False,
             )
             self._fns[key] = jax.jit(fn)
         return self._fns[key]

@@ -8,4 +8,14 @@
 #                detector's registry fallback (DESIGN.md §9; numpy ref.py
 #                is bit-identical, not just allclose)
 # Each kernel ships <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-# wrapper with CPU interpret fallback) and ref.py (pure-jnp oracle).
+# wrapper: interpret mode on the CPU backend, compiled on an accelerator) and
+# ref.py (numpy/jnp oracle).
+
+
+def on_cpu() -> bool:
+    """True on JAX's CPU backend: kernel wrappers default to interpret mode
+    and the batched executor to its host two-pass. Anywhere else the kernels
+    are compiled for the device, with no fallback."""
+    import jax
+
+    return jax.default_backend() == "cpu"

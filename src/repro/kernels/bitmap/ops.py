@@ -12,12 +12,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import on_cpu
 from repro.kernels.bitmap.bitmap import combine_pallas
 from repro.kernels.bitmap.ref import Program
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def pack_mask(mask: jnp.ndarray) -> jnp.ndarray:
@@ -57,7 +54,7 @@ def combine_bitmaps(
     include padding even under NOT.
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = on_cpu()
     leaves = jnp.asarray(leaves, jnp.uint32)
     K, W = leaves.shape
     block = min(1024, -(-W // 128) * 128)

@@ -13,11 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import on_cpu
 from repro.kernels.fused.fused import fused_scrub_jls_pallas
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("sv", "bits", "bh", "interpret"))
@@ -43,7 +40,7 @@ def fused_scrub_residuals(
     w<=0/h<=0. Returns int32 (N, H, W) residuals of the scrubbed image.
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = on_cpu()
     images = jnp.asarray(images)
     rects = jnp.asarray(rects, jnp.int32)
     if bits is None:

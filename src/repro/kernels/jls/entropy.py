@@ -26,6 +26,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import on_cpu
 
 _QMAX = 23  # mirrors codec._QMAX; a shared constant test pins them together
 _ESC_LEN = _QMAX + 2 + 64
@@ -35,20 +38,18 @@ def _zigzag_rowsum_kernel(res_ref, u_ref, rs_ref):
     r = res_ref[0]  # (bh, W) int32
     u = (r << 1) ^ (r >> 31)  # zigzag: non-negative, <= 2^17 for 16-bit planes
     u_ref[0] = u
-    rs_ref[0] = jnp.sum(u, axis=1)
+    # one lane-dense (1, bh) row of sums per stripe: a TPU block's last two
+    # dims must be tile-aligned or full, which a (1, bh) slice of (N, H) is not
+    rs_ref[0, 0] = jnp.sum(u, axis=1).reshape(1, -1)
 
 
 def _len_rem_kernel(k_ref, u_ref, len_ref, rem_ref):
-    kv = k_ref[0, 0]  # per-instance Rice parameter
+    kv = k_ref[pl.program_id(0)]  # per-instance Rice parameter, from SMEM
     u = u_ref[0]  # (bh, W) int32 zigzag magnitudes
     q = jax.lax.shift_right_logical(u, kv)
     esc = q > _QMAX
     len_ref[0] = jnp.where(esc, _ESC_LEN, q + 1 + kv)
     rem_ref[0] = u & ((1 << kv) - 1)
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("bh", "interpret"))
@@ -62,15 +63,15 @@ def _prepass(res, bh, interpret):
         in_specs=[pl.BlockSpec((1, bh, W), lambda n, i: (n, i, 0))],
         out_specs=[
             pl.BlockSpec((1, bh, W), lambda n, i: (n, i, 0)),
-            pl.BlockSpec((1, bh), lambda n, i: (n, i)),
+            pl.BlockSpec((1, 1, 1, bh), lambda n, i: (n, i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((N, Hp, W), jnp.int32),
-            jax.ShapeDtypeStruct((N, Hp), jnp.int32),
+            jax.ShapeDtypeStruct((N, Hp // bh, 1, bh), jnp.int32),
         ],
         interpret=interpret,
     )(padded)
-    return u[:, :H, :], rs[:, :H]
+    return u[:, :H, :], rs.reshape(N, Hp)[:, :H]
 
 
 def rice_prepass(
@@ -84,7 +85,7 @@ def rice_prepass(
     previous chunk.
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = on_cpu()
     return _prepass(jnp.asarray(res, jnp.int32), bh, interpret)
 
 
@@ -93,17 +94,17 @@ def _len_rem(u, ks, bh, interpret):
     N, H, W = u.shape
     Hp = (H + bh - 1) // bh * bh
     padded = u if Hp == H else jnp.pad(u, ((0, 0), (0, Hp - H), (0, 0)))
+    stripe = pl.BlockSpec((1, bh, W), lambda n, i, ks: (n, i, 0))
     lens, rem = pl.pallas_call(
         _len_rem_kernel,
-        grid=(N, Hp // bh),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda n, i: (n, 0)),
-            pl.BlockSpec((1, bh, W), lambda n, i: (n, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bh, W), lambda n, i: (n, i, 0)),
-            pl.BlockSpec((1, bh, W), lambda n, i: (n, i, 0)),
-        ],
+        # ks rides in SMEM as a scalar-prefetch operand: k is a scalar per
+        # instance, and a (1, 1) VMEM block of (N, 1) is not a legal TPU block
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(N, Hp // bh),
+            in_specs=[stripe],
+            out_specs=[stripe, stripe],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((N, Hp, W), jnp.int32),
             jax.ShapeDtypeStruct((N, Hp, W), jnp.int32),
@@ -122,10 +123,13 @@ def rice_len_rem(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Per-symbol code lengths + k-bit remainder words for a zigzag batch.
 
-    ``ks`` is the per-instance Rice parameter, shape (N,) or (N, 1) int32.
-    Returns device arrays; asynchronous like :func:`rice_prepass`.
+    ``ks`` is the per-instance Rice parameter, shape (N,) or (N, 1) int32,
+    one per frame of ``u``. Returns device arrays; asynchronous like
+    :func:`rice_prepass`.
     """
     if interpret is None:
-        interpret = _on_cpu()
-    ks = jnp.asarray(ks, jnp.int32).reshape(-1, 1)
+        interpret = on_cpu()
+    ks = jnp.asarray(ks, jnp.int32).reshape(-1)
+    if ks.shape[0] != u.shape[0]:
+        raise ValueError(f"{ks.shape[0]} Rice parameters for {u.shape[0]} frames")
     return _len_rem(jnp.asarray(u, jnp.int32), ks, bh, interpret)

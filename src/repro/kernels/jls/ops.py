@@ -7,11 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import on_cpu
 from repro.kernels.jls.jls import jls_residuals_pallas
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("sv", "bits", "bh", "interpret"))
@@ -30,7 +27,7 @@ def jls_residuals(
 ) -> jnp.ndarray:
     """Batched predictor residuals (N, H, W) -> int32 (N, H, W)."""
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = on_cpu()
     images = jnp.asarray(images)
     if bits is None:
         bits = images.dtype.itemsize * 8

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import on_cpu
 from repro.kernels.phi_detect.phi_detect import phi_detect_pallas
 
 # Default gradient threshold: burned-in glyph strokes are max-contrast
@@ -47,10 +48,6 @@ def stored_max_value(ds) -> float:
     return full_scale(dt)
 
 
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
-
-
 @functools.partial(jax.jit, static_argnames=("thresh", "tile", "interpret"))
 def _detect(images, thresh, tile, interpret):
     return phi_detect_pallas(images, thresh=thresh, tile=tile, interpret=interpret)
@@ -71,7 +68,7 @@ def edge_density(
     narrower, e.g. 4095 for 12-bit data held in uint16.
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = on_cpu()
     images = jnp.asarray(images)
     if thresh is None:
         thresh = full_scale(images.dtype, max_value) * DEFAULT_THRESH_FRAC

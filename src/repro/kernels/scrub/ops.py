@@ -13,13 +13,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import on_cpu
 from repro.kernels.scrub.scrub import scrub_pallas
 
 _SUBLANE = {1: 32, 2: 16, 4: 8, 8: 8}  # dtype itemsize -> min sublane tile
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def default_block(dtype: jnp.dtype, H: int, W: int) -> tuple[int, int]:
@@ -54,7 +51,7 @@ def scrub_images(
     w<=0/h<=0. Returns same shape/dtype.
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = on_cpu()
     images = jnp.asarray(images)
     rects = jnp.asarray(rects, jnp.int32)
     N, H, W = images.shape

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.detect.policy import DEFAULT_BINARIZE_FRAC as BINARIZE_FRAC
+from repro.kernels import on_cpu
 from repro.kernels.phi_detect.ops import full_scale, stored_max_value  # noqa: F401
 from repro.kernels.textdetect.textdetect import textdetect_pallas
 
@@ -23,10 +24,6 @@ from repro.kernels.textdetect.textdetect import textdetect_pallas
 def binarize_thresh(dtype, max_value: float | None = None) -> float:
     """Dtype-aware glyph threshold (same ceiling logic as ``phi_detect``)."""
     return full_scale(dtype, max_value) * BINARIZE_FRAC
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("thresh", "tile", "interpret"))
@@ -49,7 +46,7 @@ def tile_profiles(
     ``max_value`` for BitsStored-style narrow ranges held in wide words).
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = on_cpu()
     images = jnp.asarray(images)
     if thresh is None:
         thresh = binarize_thresh(images.dtype, max_value)

@@ -52,7 +52,8 @@ def tile_profiles_ref(
     b = binarize_np(images, thresh).reshape(N, H // th, th, W // tw, tw)
     rows = np.ascontiguousarray(b.sum(axis=4, dtype=np.int32).transpose(0, 1, 3, 2))
     cols = b.sum(axis=2, dtype=np.int32)
-    # max-run scan, identical recurrence to the kernel's fori_loop:
+    # max-run scan (the kernel computes the same runs as column minus the
+    # last gap column, restarted per tile):
     # run_j = (run_{j-1} + b_j) * b_j
     run = np.zeros((N, H // th, th, W // tw), np.int32)
     best = np.zeros_like(run)

@@ -47,6 +47,10 @@ from repro.training.optimizer import AdamWState
 from repro.training.train_step import TrainState, make_train_step
 from repro.training import cosine_schedule
 
+# cross-pod (data-center network) bytes/s per chip: an assumption, no
+# published figure exists for it
+_DCI_BW = 25e9
+
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
 
@@ -181,10 +185,11 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, *, overrides: dict |
 
     flops, bts = static["flops"], static["bytes"]
     intra, cross = static["coll_intra"], static["coll_cross"]
+    chip = hw.peaks(hw.V5E)  # the production mesh is modeled as v5e chips
     record["roofline"] = {
-        "compute_s": flops / hw.PEAK_FLOPS_BF16 if flops > 0 else None,
-        "memory_s": bts / hw.HBM_BW if bts > 0 else None,
-        "collective_s": intra / hw.ICI_BW + cross / hw.DCI_BW,
+        "compute_s": flops / chip.flops_bf16 if flops > 0 else None,
+        "memory_s": bts / chip.hbm_bw if bts > 0 else None,
+        "collective_s": intra / chip.ici_bw + cross / _DCI_BW,
         "collective_bytes_intra": intra,
         "collective_bytes_cross_pod": cross,
     }

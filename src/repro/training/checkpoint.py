@@ -28,11 +28,7 @@ import numpy as np
 
 
 def _flatten_with_paths(tree: Any):
-    # jax.tree.flatten_with_path landed after 0.4.x; fall back to tree_util
-    flatten_with_path = getattr(
-        jax.tree, "flatten_with_path", jax.tree_util.tree_flatten_with_path
-    )
-    flat, treedef = flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     out = {}
     for path, leaf in flat:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)

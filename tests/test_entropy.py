@@ -196,6 +196,15 @@ class TestDevicePrepass:
             np.testing.assert_array_equal(plans_d[j].lens, plan_h.lens)
             assert codec.rice_pack(plans_d[j]) == codec.rice_pack(plan_h)
 
+    def test_len_rem_wants_one_k_per_frame(self):
+        """The executor pads a chunk's batch dim; the kernel reads a k for
+        every padded frame, so a short ks is refused, not read past."""
+        from repro.kernels.jls import entropy
+
+        u = np.zeros((4, 16, 16), np.int32)
+        with pytest.raises(ValueError, match="3 Rice parameters for 4 frames"):
+            entropy.rice_len_rem(u, np.zeros(3, np.int32), bh=16)
+
     def test_non_multiple_block_height_padding(self, rng):
         # H=20 with bh=16 exercises the pad/crop path in both kernels
         imgs = (rng.random((2, 20, 24)) * 255).astype(np.uint8)
