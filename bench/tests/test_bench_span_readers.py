@@ -1,0 +1,88 @@
+"""The readers of the executor's and the pipeline's phase spans, on small
+recorded span lists: shares of the window, the copy rate and the pack's
+cores. Each reads nothing, and returns None, where the program has no such
+span."""
+import pytest
+
+import harness
+from repro.obs.trace import Span
+
+SHARES = {
+    "device_wait_share.export": "kernel.device_wait",
+    "d2h_share.export": "kernel.d2h",
+    "lake_lookup_share.export": "pipeline.lake_lookup",
+    "filter_share.export": "pipeline.filter",
+    "anonymize_share.export": "pipeline.anonymize",
+    "lake_put_share.export": "pipeline.lake_put",
+}
+NEW = sorted(SHARES) + ["d2h_GBps.export", "pack_cores.export"]
+
+
+def _run(spans, t0=10.0, t1=20.0):
+    cell = harness.Cell("ct_export_cold", {}, {"loop": "closed"}, 1, [], [])
+    recorded = [Span("t", f"s{i:08d}", None, name, a, b, seq=i, attrs=attrs)
+                for i, (name, a, b, attrs) in enumerate(spans, 1)]
+    return harness.Run(cell, t0, t1, [], recorded)
+
+
+def _read(metric, run):
+    return harness.load_reader(metric)(run)
+
+
+@pytest.mark.parametrize("metric", sorted(SHARES))
+def test_share_of_the_window(metric):
+    name = SHARES[metric]
+    run = _run([(name, 11.0, 11.5, {}), (name, 12.0, 13.0, {}),
+                (name, 9.0, 10.5, {}),          # starts before the window
+                (name, 19.5, 20.5, {}),         # ends after it
+                ("other", 11.0, 15.0, {})])
+    assert _read(metric, run) == pytest.approx(100.0 * 1.5 / 10.0)
+
+
+def test_d2h_rate_is_bytes_over_copy_time():
+    run = _run([("kernel.d2h", 11.0, 11.5, {"bytes": 2_000_000_000}),
+                ("kernel.d2h", 12.0, 12.25, {"bytes": 1_000_000_000}),
+                ("kernel.device_wait", 12.0, 15.0, {})])
+    assert _read("d2h_GBps.export", run) == pytest.approx(3.0 / 0.75)
+
+
+def test_pack_cores_over_overlapping_jobs():
+    # four jobs: two overlap fully, one partly, one stands alone; the union
+    # of their intervals is [11, 13) and [14, 15): 3 s
+    run = _run([("kernel.pack_job", 11.0, 12.0, {"cpu_s": 0.9}),
+                ("kernel.pack_job", 11.0, 12.0, {"cpu_s": 1.0}),
+                ("kernel.pack_job", 11.5, 13.0, {"cpu_s": 1.5}),
+                ("kernel.pack_job", 14.0, 15.0, {"cpu_s": 0.6}),
+                ("kernel.pack_job", 19.0, 21.0, {"cpu_s": 2.0})])  # outside
+    assert _read("pack_cores.export", run) == pytest.approx(4.0 / 3.0)
+
+
+def test_pack_cores_needs_cpu_seconds():
+    # a simulated clock leaves cpu_s out: nothing to read
+    run = _run([("kernel.pack_job", 11.0, 12.0, {})])
+    assert _read("pack_cores.export", run) is None
+
+
+@pytest.mark.parametrize("metric", NEW)
+def test_silent_on_a_program_without_the_spans(metric):
+    run = _run([("pipeline.run_study", 11.0, 19.0, {}),
+                ("kernel.entropy_code", 12.0, 14.0, {"wait_s": 0.5})])
+    assert _read(metric, run) is None
+
+
+@pytest.mark.usefixtures("jax_config_restored")
+def test_traced_cpu_run_reads_the_pipeline_phases():
+    """The served path at test size on the CPU, traced: the four phases fit
+    inside the pipeline's own host time, and the pack reports its cores.
+    The CPU takes the executor's host path, which makes no device copy."""
+    from test_bench_cell import run, tiny_cell
+
+    phases = ["lake_lookup_share.export", "filter_share.export",
+              "anonymize_share.export", "lake_put_share.export"]
+    r = run(tiny_cell("export_cold", per_layer=["host_self_share.export", "pack_cores.export",
+                                                "d2h_share.export", *phases]), trace=True)
+    assert r["correct"], r["checks"]
+    m = {k: v["value"] for k, v in r["metrics"].items()}
+    assert set(phases) <= set(m) and "d2h_share.export" not in m
+    assert 0 < sum(m[p] for p in phases) <= m["host_self_share.export"]
+    assert m["pack_cores.export"] > 0

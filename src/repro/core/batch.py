@@ -28,6 +28,11 @@ wants:
   pure functions of per-instance arrays and are drained in submission
   order, so payload bytes are identical for any pool size — including the
   inline ``host_workers=0`` mode.
+* **spans** — under ``kernel.entropy_code`` the collect phase records where
+  the time goes: ``kernel.device_wait`` (blocking on the chunk's device
+  outputs), ``kernel.d2h`` (each device->host copy, with its ``bytes``) and
+  one ``kernel.pack_job`` per frame, timed on the pool thread that ran it
+  and entered by the collector in chunk order (DESIGN.md §11).
 
 The executor owns dispatch statistics and a lazily created pack pool; one
 instance can serve every stage/pipeline combination and is safe to share
@@ -35,8 +40,10 @@ across the (single-threaded) worker pool simulation.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import os
+import time
 from collections import defaultdict, deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
@@ -258,13 +265,54 @@ class BatchedDeidExecutor:
         Job order == chunk order either way, so drain order (and therefore
         every output byte) is independent of the pool size."""
         pool = self._ensure_pool()
+        if self.tracer.enabled:
+            fns = [self._timed(fn) for fn in fns]
         if pool is None:
             return list(fns)  # evaluated lazily, in order, on collect
         return [pool.submit(fn) for fn in fns]
 
-    @staticmethod
-    def _job_result(job):
-        return job.result() if hasattr(job, "result") else job()
+    def _timed(self, fn):
+        """``fn`` as a job that also returns when it ran on the tracer's
+        clock and, on the wall clock, the CPU seconds of the thread that ran
+        it (left out under a simulated clock, whose trace must replay)."""
+        tracer, wall = self.tracer, self.tracer.wall
+
+        def job():
+            c0 = time.thread_time() if wall else 0.0
+            t0 = tracer.clock.now()
+            with tracer.annotation("kernel.pack_job") or contextlib.nullcontext():
+                result = fn()
+            t1 = tracer.clock.now()
+            return result, t0, t1, (time.thread_time() - c0 if wall else None)
+
+        return job
+
+    def _job_result(self, job):
+        """The job's result; with tracing on, its run is entered as a closed
+        ``kernel.pack_job`` span under the open ``kernel.entropy_code``."""
+        result = job.result() if hasattr(job, "result") else job()
+        if not self.tracer.enabled:
+            return result
+        result, t0, t1, cpu_s = result
+        self.tracer.record(
+            "kernel.pack_job", t0, t1, **({} if cpu_s is None else {"cpu_s": cpu_s})
+        )
+        return result
+
+    def _wait(self, *arrays) -> None:
+        """Block until the chunk's device outputs are ready."""
+        import jax
+
+        with self.tracer.span("kernel.device_wait"):
+            jax.block_until_ready(arrays)
+
+    def _d2h(self, name: str, array) -> np.ndarray:
+        """Copy one ready device array to the host; ``bytes`` counts what
+        crossed, padding frames included."""
+        with self.tracer.span("kernel.d2h", array=name) as sp:
+            host = np.asarray(array)
+            sp.set(bytes=host.nbytes)
+        return host
 
     # ------------------------------------------------------------- planning
     def supports(self, pixels: Optional[np.ndarray], recompress: bool) -> bool:
@@ -431,7 +479,8 @@ class BatchedDeidExecutor:
             return
 
         if st.kind == "scrub_only":
-            scrubbed = np.asarray(st.scrubbed)  # blocks on the device here
+            self._wait(st.scrubbed)
+            scrubbed = self._d2h("scrubbed", st.scrubbed)
             for j, i in enumerate(chunk):
                 pixels = items[i][0]
                 pixels[...] = scrubbed[j]
@@ -440,7 +489,8 @@ class BatchedDeidExecutor:
 
         # recompress paths: the host Golomb-Rice tail — its own span so a
         # trace shows the host/device boundary (queue_s = how long the chunk
-        # sat in flight behind newer dispatches, wait_s = device sync time)
+        # sat in flight behind newer dispatches, wait_s = device syncs and
+        # copies, the k loop and the _len_rem dispatch)
         # NB: pool size / pipeline depth are deliberately NOT span attrs —
         # the trace digest must be identical for any host_workers setting
         with self.tracer.span(
@@ -450,7 +500,8 @@ class BatchedDeidExecutor:
             if st.kind == "device_plan":
                 from repro.kernels.jls import entropy
 
-                rs = np.asarray(st.rs)  # device sync point
+                self._wait(st.rs, st.u)
+                rs = self._d2h("rs", st.rs)
                 # one k per padded frame: padding frames get k=0 and are
                 # never packed, but the kernel reads a k for every frame
                 ks = np.zeros(st.u.shape[0], np.int32)
@@ -459,8 +510,9 @@ class BatchedDeidExecutor:
                 lens_d, rem_d = entropy.rice_len_rem(
                     st.u, ks, bh=self.bh, interpret=self.interpret
                 )
-                u_np = np.asarray(st.u).reshape(st.u.shape[0], -1)
-                lens_np, rem_np = np.asarray(lens_d), np.asarray(rem_d)
+                u_np = self._d2h("u", st.u).reshape(st.u.shape[0], -1)
+                self._wait(lens_d, rem_d)
+                lens_np, rem_np = self._d2h("lens", lens_d), self._d2h("rem", rem_d)
                 st.jobs = self._submit_jobs(
                     [
                         lambda j=j: codec.rice_pack(
@@ -473,7 +525,8 @@ class BatchedDeidExecutor:
                 )
                 kparams = [int(k) for k in ks[: len(chunk)]]
             elif st.kind == "device_res":
-                res = np.asarray(st.res)  # device sync point
+                self._wait(st.res)
+                res = self._d2h("res", st.res)
                 st.jobs = self._submit_jobs(
                     [lambda rj=res[j]: codec.rice_encode(rj) for j in range(len(chunk))]
                 )

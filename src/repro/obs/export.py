@@ -43,8 +43,9 @@ ALLOWED_ATTR_KEYS = frozenset({
     "shape", "dtype", "bucket", "path", "interpret", "padded",
     # timing facts
     "busy_s", "t_lease", "visibility",
-    # device/host pipeline boundary timing (DESIGN.md §12)
-    "queue_s", "wait_s",
+    # device/host pipeline boundary timing (DESIGN.md §12): device->host
+    # copies and the pack pool's CPU time
+    "queue_s", "wait_s", "bytes", "array", "cpu_s",
     # outcome flags
     "ok", "deduped", "fenced", "crashed", "mode",
     # SLO / critical-path profile plane (DESIGN.md §13)

@@ -14,6 +14,12 @@ Design constraints, in order:
 3. **Single-threaded context.** The whole stack is step-driven off one event
    loop, so the active-span *stack* is the context: a span opened inside
    another parents to it automatically; roots name their trace explicitly.
+   Work timed on other threads is handed back and entered, closed, with
+   :meth:`Tracer.record` from the thread that owns the stack.
+4. **One clock with the device trace.** On a :class:`WallClock`, once JAX is
+   imported, every span also enters a ``jax.profiler.TraceAnnotation`` of
+   its name, so a profiler trace shows the program's spans on its host
+   plane beside the device's operations. The simulated clock never does.
 
 Spans never carry free-text values from data; attributes cross the
 :mod:`repro.obs.export` redactor before leaving the process.
@@ -22,8 +28,11 @@ from __future__ import annotations
 
 import hashlib
 import json
+import sys
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
+
+from repro.utils.timing import WallClock
 
 
 def trace_id_for(key: str, attempt: int = 1) -> str:
@@ -126,6 +135,11 @@ class Tracer:
         self.finished: List[Span] = []
         self._stack: List[Span] = []
         self._seq = 0
+        # real time: spans mirror into the profiler's trace, and work timed
+        # on other threads may carry its CPU seconds
+        self.wall = isinstance(clock, WallClock)
+        self._annotation_cls = None  # jax.profiler.TraceAnnotation once found
+        self._annotations: Dict[int, object] = {}  # open span seq -> annotation
 
     # -- recording ---------------------------------------------------------
 
@@ -133,22 +147,52 @@ class Tracer:
         """Open a span. Parents to the innermost open span; a root span with
         no explicit ``trace_id`` gets one minted from its own sequence number
         (deterministic)."""
+        span = self._new(name, trace_id, self.clock.now(), attrs)
+        self._stack.append(span)
+        ann = self.annotation(name)
+        if ann is not None:
+            ann.__enter__()
+            self._annotations[span.seq] = ann
+        return _ActiveSpan(self, span)
+
+    def record(self, name: str, t0: float, t1: float, **attrs) -> Span:
+        """Enter an already closed span, timed elsewhere (e.g. on a pool
+        thread), as a child of the innermost open span."""
+        span = self._new(name, None, t0, attrs)
+        span.t1 = t1
+        self.finished.append(span)
+        return span
+
+    def _new(self, name: str, trace_id: Optional[str], t0: float, attrs: dict) -> Span:
         self._seq += 1
         seq = self._seq
         parent = self._stack[-1] if self._stack else None
         if trace_id is None:
             trace_id = parent.trace_id if parent is not None else f"root{seq:08d}"
-        span = Span(
+        return Span(
             trace_id=trace_id,
             span_id=f"s{seq:08d}",
             parent_id=parent.span_id if parent is not None and parent.trace_id == trace_id else None,
             name=name,
-            t0=self.clock.now(),
+            t0=t0,
             seq=seq,
             attrs=dict(attrs),
         )
-        self._stack.append(span)
-        return _ActiveSpan(self, span)
+
+    def annotation(self, name: str):
+        """A ``jax.profiler.TraceAnnotation`` named ``name`` when this tracer
+        runs on the wall clock and JAX is already imported, else None. JAX is
+        never imported from here, so host-only code and the sim stay free of
+        it."""
+        if not self.wall:
+            return None
+        if self._annotation_cls is None:
+            if "jax" not in sys.modules:
+                return None
+            from jax.profiler import TraceAnnotation
+
+            self._annotation_cls = TraceAnnotation
+        return self._annotation_cls(name)
 
     def event(self, name: str, trace_id: Optional[str] = None, **attrs) -> Span:
         """Instant (zero-duration) span, e.g. a broker publish or an ack."""
@@ -162,6 +206,9 @@ class Tracer:
             self._stack.pop()
         elif span in self._stack:  # pragma: no cover - misuse guard
             self._stack.remove(span)
+        ann = self._annotations.pop(span.seq, None)
+        if ann is not None:
+            ann.__exit__(None, None, None)
         span.t1 = self.clock.now()
         self.finished.append(span)
 
@@ -198,6 +245,7 @@ class Tracer:
     def clear(self) -> None:
         self.finished.clear()
         self._stack.clear()
+        self._annotations.clear()
         self._seq = 0
 
 
@@ -211,6 +259,9 @@ class NullTracer:
         return _NOOP_SPAN
 
     def event(self, name: str, trace_id: Optional[str] = None, **attrs) -> None:
+        return None
+
+    def record(self, name: str, t0: float, t1: float, **attrs) -> None:
         return None
 
     @property

@@ -61,6 +61,8 @@ class TestOverlapDeterminism:
             )
             _run(ex, items)
             assert tracer.spans("kernel.entropy_code")  # the tail was traced
+            # each frame's pack job is in the digest, timed on the pool
+            assert len(tracer.spans("kernel.pack_job")) == len(items)
             digests.add(tracer.digest())
             ex.close()
         assert len(digests) == 1
