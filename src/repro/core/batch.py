@@ -22,7 +22,8 @@ wants:
   serializing. On the kernel path the device also runs the Golomb-Rice
   *plan* pre-pass (``kernels/jls/entropy``: zigzag + row sums, then
   per-symbol code lengths + remainders), leaving the host only the final
-  unary splice (``codec.rice_pack``).
+  splice (``codec.rice_pack``), which reads those lengths and remainders
+  as they arrive.
 * **host tail** — per-instance pack/encode jobs are embarrassingly parallel
   and fan out across a small thread pool (numpy releases the GIL); jobs are
   pure functions of per-instance arrays and are drained in submission
@@ -31,8 +32,9 @@ wants:
 * **spans** — under ``kernel.entropy_code`` the collect phase records where
   the time goes: ``kernel.device_wait`` (blocking on the chunk's device
   outputs), ``kernel.d2h`` (each device->host copy, with its ``bytes``) and
-  one ``kernel.pack_job`` per frame, timed on the pool thread that ran it
-  and entered by the collector in chunk order (DESIGN.md §11).
+  one ``kernel.pack_job`` per frame with its ``escapes`` count, timed on
+  the pool thread that ran it and entered by the collector in chunk order
+  (DESIGN.md §11).
 
 The executor owns dispatch statistics and a lazily created pack pool; one
 instance can serve every stage/pipeline combination and is safe to share
@@ -75,6 +77,12 @@ def _pow2_at_least(n: int, cap: Optional[int] = None) -> int:
         # non-power-of-two batch dim, silently growing the jit-cache shape set
         p = min(p, _pow2_floor(cap))
     return p
+
+
+def _pack(plan: codec.RicePlan) -> Tuple[bytes, int, int]:
+    """One frame's pack job: the splice of ``plan``, as (payload, k, escapes)."""
+    payload, escapes = codec.rice_pack(plan)
+    return payload, plan.k, escapes
 
 
 def blank_inplace(pixels: np.ndarray, rects: Sequence[Rect]) -> np.ndarray:
@@ -288,14 +296,16 @@ class BatchedDeidExecutor:
         return job
 
     def _job_result(self, job):
-        """The job's result; with tracing on, its run is entered as a closed
-        ``kernel.pack_job`` span under the open ``kernel.entropy_code``."""
+        """The job's (payload, k, escapes); with tracing on, its run is
+        entered as a closed ``kernel.pack_job`` span, with the frame's escape
+        count, under the open ``kernel.entropy_code``."""
         result = job.result() if hasattr(job, "result") else job()
         if not self.tracer.enabled:
             return result
         result, t0, t1, cpu_s = result
         self.tracer.record(
-            "kernel.pack_job", t0, t1, **({} if cpu_s is None else {"cpu_s": cpu_s})
+            "kernel.pack_job", t0, t1, escapes=result[2],
+            **({} if cpu_s is None else {"cpu_s": cpu_s}),
         )
         return result
 
@@ -460,7 +470,9 @@ class BatchedDeidExecutor:
             # intermediates stay cache-resident, a whole chunk's do not
             st.jobs = self._submit_jobs(
                 [
-                    lambda px=items[i][0]: codec.rice_encode(codec.residuals(px, sv))
+                    lambda px=items[i][0]: _pack(
+                        codec.rice_plan(codec.residuals(px, sv))
+                    )
                     for i in chunk
                 ]
             )
@@ -510,38 +522,32 @@ class BatchedDeidExecutor:
                 lens_d, rem_d = entropy.rice_len_rem(
                     st.u, ks, bh=self.bh, interpret=self.interpret
                 )
-                u_np = self._d2h("u", st.u).reshape(st.u.shape[0], -1)
+                u_np = self._d2h("u", st.u)
                 self._wait(lens_d, rem_d)
                 lens_np, rem_np = self._d2h("lens", lens_d), self._d2h("rem", rem_d)
                 st.jobs = self._submit_jobs(
                     [
-                        lambda j=j: codec.rice_pack(
-                            codec.rice_plan_from_prepass(
-                                u_np[j], int(ks[j]), lens_np[j], rem_np[j]
-                            )
+                        lambda j=j: _pack(
+                            codec.RicePlan(int(ks[j]), lens_np[j], rem_np[j], u_np[j])
                         )
                         for j in range(len(chunk))
                     ]
                 )
-                kparams = [int(k) for k in ks[: len(chunk)]]
             elif st.kind == "device_res":
                 self._wait(st.res)
                 res = self._d2h("res", st.res)
                 st.jobs = self._submit_jobs(
-                    [lambda rj=res[j]: codec.rice_encode(rj) for j in range(len(chunk))]
+                    [
+                        lambda rj=res[j]: _pack(codec.rice_plan(rj))
+                        for j in range(len(chunk))
+                    ]
                 )
-                kparams = None
-            else:  # host_encode — jobs were queued at submit
-                kparams = None
+            # host_encode: jobs were queued at submit
             t1 = clk.now() if clk is not None else None
 
             total = 0
             for j, i in enumerate(chunk):
-                result = self._job_result(st.jobs[j])
-                if kparams is not None:
-                    payload, k = result, kparams[j]
-                else:
-                    payload, k = result
+                payload, k, _ = self._job_result(st.jobs[j])
                 total += len(payload)
                 out[i] = BatchOutput(
                     pixels=items[i][0],

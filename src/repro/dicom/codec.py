@@ -10,11 +10,11 @@ Huffman entropy coding. We implement the same two-phase structure:
 * **entropy coding** — Golomb-Rice with per-image parameter + escape codes.
   The coder is split into two phases (DESIGN.md §12): a **plan** phase
   (:func:`rice_plan`) that derives the zigzag magnitudes, the Rice parameter
-  ``k``, per-symbol code lengths, and their prefix-sum bit offsets — all
-  vectorizable, and computable on the accelerator by the ``kernels/jls``
-  entropy pre-pass — and a **pack** phase (:func:`rice_pack`) that splices
-  the variable-length codes into the final bitstream with word-level
-  scatter-OR writes. Only the pack splice is inherently host work.
+  ``k``, per-symbol code lengths and remainder words — all vectorizable, and
+  computable on the accelerator by the ``kernels/jls`` entropy pre-pass —
+  and a **pack** phase (:func:`rice_pack`) that prefix-sums the lengths
+  into bit offsets and splices the variable-length codes into the final
+  bitstream with word-level scatter-OR writes. Only the splice is host work.
 
 Round-trips are exact (lossless) — asserted by unit + property tests.
 """
@@ -27,7 +27,8 @@ from typing import Optional, Tuple
 import numpy as np
 
 MAGIC = b"RJLS"
-_QMAX = 23  # unary quotient cap; larger quotients use a 32-bit escape
+_QMAX = 23  # unary quotient cap; larger quotients use a 64-bit escape
+_ESC_LEN = _QMAX + 2 + 64  # escape code: QMAX+1 ones, stop bit, raw 64 bits
 
 
 # --------------------------------------------------------------- prediction
@@ -193,133 +194,91 @@ def _rice_k(u: np.ndarray) -> int:
 
 @dataclass
 class RicePlan:
-    """Phase-1 output of the Golomb-Rice coder: everything except the splice.
+    """Phase-1 output of the Golomb-Rice coder: everything the splice reads.
 
-    ``u`` are the zigzag magnitudes, ``lens`` the per-symbol code lengths,
-    ``offs`` their exclusive prefix-sum bit offsets (len n+1). ``rem`` is the
-    optional pre-extracted k-bit remainder word per symbol — the device
-    entropy pre-pass hands it back so the host pack never touches ``u`` for
-    non-escape symbols.
+    ``lens`` is each symbol's code length (``_ESC_LEN`` marks an escape) and
+    ``rem`` its k-bit remainder word. ``u``, the zigzag magnitudes, is read
+    only at escapes, whose code carries it raw. The device entropy pre-pass
+    hands back all three as int32 frames; :func:`rice_plan` derives them on
+    the host. Either way the same :func:`rice_pack` splices them.
     """
 
     k: int
-    u: np.ndarray
-    q: np.ndarray
-    esc: np.ndarray
     lens: np.ndarray
-    offs: np.ndarray
-    rem: Optional[np.ndarray] = None
-
-    @property
-    def total_bits(self) -> int:
-        return int(self.offs[-1])
+    rem: np.ndarray
+    u: np.ndarray
 
 
 def rice_plan(res: np.ndarray) -> RicePlan:
-    """Host plan phase: zigzag, k, quotients, code lengths, bit offsets."""
+    """Host plan phase: zigzag, k, code lengths and remainder words."""
     u = _zigzag(res.ravel())
     k = _rice_k(u)
-    return _plan_from_u(u, k)
-
-
-def _plan_from_u(u: np.ndarray, k: int) -> RicePlan:
-    q = (u >> np.uint64(k)).astype(np.int64)
-    esc = q > _QMAX
+    q = u >> np.uint64(k)
     # bit lengths: unary(q)+stop + k remainder; escape: QMAX+1 ones + stop + 64 raw
-    lens = np.where(esc, _QMAX + 2 + 64, q + 1 + k)
-    offs = np.empty(lens.size + 1, np.int64)
-    offs[0] = 0
-    np.cumsum(lens, out=offs[1:])
-    return RicePlan(k=k, u=u, q=q, esc=esc, lens=lens, offs=offs)
+    lens = np.where(q > _QMAX, _ESC_LEN, q + (1 + k))
+    return RicePlan(k=k, lens=lens, rem=u & np.uint64((1 << k) - 1), u=u)
 
 
-def rice_plan_from_prepass(
-    u: np.ndarray, k: int, lens: np.ndarray, rem: Optional[np.ndarray] = None
-) -> RicePlan:
-    """Plan from the device entropy pre-pass (``kernels/jls`` length kernel):
-    the device already computed zigzag magnitudes, per-symbol code lengths,
-    and remainder words; the host only prefix-sums the lengths. Bit-identical
-    to :func:`rice_plan` on the same residuals (parity-tested)."""
-    u = u.ravel().astype(np.uint64)
-    q = (u >> np.uint64(k)).astype(np.int64)
-    esc = q > _QMAX
-    lens = lens.ravel().astype(np.int64)
-    offs = np.empty(lens.size + 1, np.int64)
-    offs[0] = 0
-    np.cumsum(lens, out=offs[1:])
-    return RicePlan(
-        k=k, u=u, q=q, esc=esc, lens=lens, offs=offs,
-        rem=None if rem is None else rem.ravel().astype(np.uint64),
-    )
-
-
-def _scatter_field(
-    words: np.ndarray, pos: np.ndarray, val: np.ndarray, nbits: np.ndarray
-) -> None:
-    """OR variable-width bit fields into an MSB-first uint64 word stream.
-
-    ``val`` (uint64) is written so its bit ``nbits-1`` lands at stream bit
-    position ``pos``. Fields are <= 64 bits, so each spans at most two words;
-    fields never overlap, so scatter-add == scatter-or (``np.add.at`` takes
-    the fast unbuffered path).
-    """
-    idx = (pos >> 6).astype(np.int64)
-    sh = 64 - (pos & 63) - nbits  # left shift into the first word (may be <0)
-    lo = sh < 0
-    first = np.where(
-        lo,
-        val >> (-sh).clip(min=0).astype(np.uint64),
-        val << sh.clip(min=0).astype(np.uint64),
-    )
-    np.add.at(words, idx, first)
-    if lo.any():
-        # low -sh bits spill left-aligned into the next word; the uint64
-        # left shift drops the already-written high bits for free
-        np.add.at(words, idx[lo] + 1, val[lo] << (64 + sh[lo]).astype(np.uint64))
-
-
-def rice_pack(plan: RicePlan) -> bytes:
+def rice_pack(plan: RicePlan) -> Tuple[bytes, int]:
     """Pack phase: splice the planned codes into the final byte stream.
 
-    Word-level construction — two vectorized scatter passes (one per field
-    kind) over uint64 words instead of materializing one byte per *bit* —
-    byte-identical to the legacy bit-array packer (property-tested).
+    A non-escape code (q ones, the stop bit, the k-bit remainder) is one
+    field of ``len = q+1+k`` bits whose value ``((2^q-1) << (k+1)) | rem``
+    is ``(2^len - 2^(k+1)) | rem``, so it comes straight from the length.
+    An escape's field is its 64 raw bits of ``u``; the QMAX+1 ones and stop
+    bit before them are ORed in by index. Byte-identical to the legacy
+    bit-array packer (property-tested). Returns (payload, escape count).
+
+    Every field is shifted into the MSB-first uint64 word that holds its
+    last bit, and the fields ending in one word, a run, are OR-reduced
+    together; the first field of a run is the one that reaches back to the
+    word's first bit, and only it can spill into the word before. Each
+    pass is one numpy call over a whole frame, and none holds the GIL for
+    its length (``np.add.at`` and an in-place cumsum would), so pack jobs
+    can run in parallel on a thread pool.
     """
-    total = plan.total_bits
-    words = np.zeros((total + 63) // 64 + 1, np.uint64)
-    offs = plan.offs[:-1]
-    k = plan.k
-    ne = ~plan.esc
-    if ne.any():
-        # non-escape: unary(q) ones + stop + k remainder is one contiguous
-        # field of q+1+k <= QMAX+1+k bits: ((2^q - 1) << (k+1)) | rem
-        q = plan.q[ne].astype(np.uint64)
-        rem = (
-            plan.rem[ne]
-            if plan.rem is not None
-            else plan.u[ne] & np.uint64((1 << k) - 1)
-        )
-        val = (((np.uint64(1) << q) - np.uint64(1)) << np.uint64(k + 1)) | rem
-        _scatter_field(words, offs[ne], val, plan.lens[ne])
-    if plan.esc.any():
-        eoffs = offs[plan.esc]
-        ones = np.full(eoffs.size, ((1 << (_QMAX + 1)) - 1) << 1, np.uint64)
-        _scatter_field(
-            words, eoffs, ones, np.full(eoffs.size, _QMAX + 2, np.int64)
-        )
-        _scatter_field(
-            words,
-            eoffs + _QMAX + 2,
-            plan.u[plan.esc],
-            np.full(eoffs.size, 64, np.int64),
-        )
-    return words.astype(">u8").tobytes()[: (total + 7) // 8]
+    lens, k = plan.lens.reshape(-1), plan.k
+    if not lens.size:
+        return b"", 0
+    # each code's last stream bit: the cumsum of the lengths, less one,
+    # 64-bit and unsigned (numpy vectorizes unsigned shifts, not signed
+    # ones); its input buffer then holds the field values
+    val = lens.astype(np.uint64)
+    val[0] -= np.uint64(1)
+    last = np.cumsum(val)
+    total = int(last[-1]) + 1
+    np.left_shift(np.uint64(1), lens, out=val, dtype=np.uint64, casting="unsafe")
+    val -= np.uint64(2 << k)
+    np.bitwise_or(val, plan.rem.reshape(-1), out=val, dtype=np.uint64, casting="unsafe")
+    esc = np.flatnonzero(lens == _ESC_LEN)
+    val[esc] = np.take(plan.u, esc)
+    # left shift that ends each field at its word's last bit: (63 - last) mod 64
+    sh = np.subtract(np.uint8(63), last, dtype=np.uint8, casting="unsafe")
+    sh &= np.uint8(63)
+    runs = np.flatnonzero(np.add(sh, lens, dtype=np.uint8, casting="unsafe") >= 64)
+    spill = (val[runs] >> np.uint64(1)) >> (np.uint64(63) - sh[runs])  # shifts < 64
+    np.left_shift(val, sh, out=val, dtype=np.uint64, casting="unsafe")
+    last >>= np.uint64(6)
+    w = last.view(np.int64)  # word of each field's last bit
+    wr = w[runs]
+    words = np.zeros((total + 63) // 64, np.uint64)  # an escape can cover a word
+    words[wr] = np.bitwise_or.reduceat(val, runs)
+    first = int(wr[0] == 0)  # a run in word 0 has no word before it
+    words[wr[first:] - 1] |= spill[first:]
+    if esc.size:  # the 25-bit prefix ends one word before the raw bits
+        we, she = w[esc], sh[esc]
+        ones = np.uint64(((1 << (_QMAX + 1)) - 1) << 1)
+        words[we - 1] |= ones << she
+        two = she > 64 - (_QMAX + 2)  # the prefix straddles into a second word
+        words[we[two] - 2] |= ones >> (np.uint64(64) - she[two])
+    payload = words.astype(">u8").view(np.uint8)[: (total + 7) // 8].tobytes()
+    return payload, int(esc.size)
 
 
 def rice_encode(res: np.ndarray) -> Tuple[bytes, int]:
     """Golomb-Rice encoder (plan + pack). Returns (payload, k)."""
     plan = rice_plan(res)
-    return rice_pack(plan), plan.k
+    return rice_pack(plan)[0], plan.k
 
 
 def rice_decode(payload: bytes, k: int, n: int) -> np.ndarray:

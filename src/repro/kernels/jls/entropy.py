@@ -12,7 +12,7 @@ onto the device and leave the host only the splice:
   plan lands on the same k as the host plan — bit-identity is what keeps
   batched == serial).
 * :func:`rice_len_rem` — given per-instance k, per-symbol code lengths and
-  the k-bit remainder words (``codec.rice_plan_from_prepass`` consumes them).
+  the k-bit remainder words (``codec.rice_pack`` splices them as they are).
 
 All arithmetic stays in int32: residuals of <=16-bit planes zigzag to <=17
 bits and a full-width row sum of those stays under 2^31 for any plausible

@@ -44,8 +44,8 @@ ALLOWED_ATTR_KEYS = frozenset({
     # timing facts
     "busy_s", "t_lease", "visibility",
     # device/host pipeline boundary timing (DESIGN.md §12): device->host
-    # copies and the pack pool's CPU time
-    "queue_s", "wait_s", "bytes", "array", "cpu_s",
+    # copies, the pack pool's CPU time and each packed frame's escape count
+    "queue_s", "wait_s", "bytes", "array", "cpu_s", "escapes",
     # outcome flags
     "ok", "deduped", "fenced", "crashed", "mode",
     # SLO / critical-path profile plane (DESIGN.md §13)

@@ -19,9 +19,9 @@ def _legacy_rice_k(u):
     return k
 
 
-def _legacy_rice_encode(res):
+def _legacy_rice_encode(res, k=None):
     u = codec._zigzag(res.ravel())
-    k = _legacy_rice_k(u)
+    k = _legacy_rice_k(u) if k is None else k
     q = (u >> k).astype(np.int64)
     rem = (u & ((1 << k) - 1)).astype(np.uint64)
     esc = q > _QMAX
@@ -48,6 +48,11 @@ def _legacy_rice_encode(res):
     return np.packbits(bits).tobytes(), k
 
 
+def _escapes(res):
+    """Escape codes in the host plan of ``res``, as the splice counts them."""
+    return codec.rice_pack(codec.rice_plan(res))[1]
+
+
 def _cases(rng):
     yield (rng.normal(128, 40, size=(64, 80))).clip(0, 255).astype(np.uint8)
     yield (rng.normal(2048, 600, size=(96, 64))).clip(0, 4095).astype(np.uint16)
@@ -71,7 +76,7 @@ class TestPackByteIdentity:
         res = np.zeros(4096, np.int64)
         hot = rng.choice(4096, size=37, replace=False)
         res[hot] = rng.integers(-(2**20), 2**20, size=37)
-        assert (codec.rice_plan(res).esc).sum() > 0  # escapes actually present
+        assert _escapes(res) > 0  # escapes actually present
         legacy_payload, legacy_k = _legacy_rice_encode(res)
         payload, k = codec.rice_encode(res)
         assert (payload, k) == (legacy_payload, legacy_k)
@@ -79,13 +84,108 @@ class TestPackByteIdentity:
     def test_plan_total_bits_matches_payload_length(self, rng):
         res = codec.residuals((rng.random((50, 60)) * 4095).astype(np.uint16), 2)
         plan = codec.rice_plan(res)
-        payload = codec.rice_pack(plan)
-        assert len(payload) == (plan.total_bits + 7) // 8
+        payload, escapes = codec.rice_pack(plan)
+        assert len(payload) == (int(plan.lens.sum()) + 7) // 8
+        assert escapes == int((plan.lens == codec._ESC_LEN).sum())
 
     def test_encode_header_roundtrip_unchanged(self, rng):
         img = (rng.random((40, 56)) * 255).astype(np.uint8)
         stream = codec.encode(img, sv=3)
         assert np.array_equal(codec.decode(stream), img)
+
+
+def _device_style_plan(res, k):
+    """The plan as the device pre-pass ships it: int32 lengths and remainder
+    words at a given ``k`` (the raw ``u`` kept wide for host-sized escapes)."""
+    u = codec._zigzag(res.ravel())
+    q = u >> np.uint64(k)
+    lens = np.where(q > _QMAX, _QMAX + 2 + 64, q + 1 + k).astype(np.int32)
+    rem = (u & np.uint64((1 << k) - 1)).astype(np.int32)
+    return codec.RicePlan(k, lens, rem, u)
+
+
+def _from_u(u):
+    """Residuals whose zigzag magnitudes are ``u``."""
+    return codec._unzigzag(np.asarray(u, np.uint64))
+
+
+def _ct_frame(rng):
+    """A 512x512 slice of ``ct_table1``'s geometry: 12-bit samples in 16-bit
+    words, a smooth body under blocky noise, one burned-in band."""
+    y = np.linspace(-1, 1, 512)[:, None]
+    x = np.linspace(-1, 1, 512)[None, :]
+    body = 0.55 * np.clip(1.0 - (x * x + y * y), 0, 1)
+    noise = np.kron(rng.random((32, 32)), np.ones((16, 16))) * 0.25
+    img = ((body + noise) * 4095 * 0.6).astype(np.uint16)
+    img[0:22, 256:512] = 4095
+    return codec.residuals(img, 1)
+
+
+# (res, k): k None lets the coder choose, as the encoder does; a fixed k
+# reaches streams the mean rule never picks (every symbol an escape)
+_SPLICE_CASES = {
+    "empty": lambda rng: (np.zeros(0, np.int64), 0),
+    "one_symbol": lambda rng: (np.array([5]), None),
+    "k0": lambda rng: (np.zeros(300, np.int64), None),
+    **{
+        f"k{t}": (lambda t: lambda rng: (
+            _from_u(rng.integers(2 ** (t - 1), 2 ** t, size=2000)), None))(t)
+        for t in (1, 3, 6, 11, 16)
+    },
+    # 128 codes of 5 bits: 640 bits, ten whole words
+    "total_bits_multiple_of_64": lambda rng: (_from_u(np.full(128, 8)), None),
+    # mixed lengths whose ends fall on the last and the next-to-last bit of
+    # words, and codes that start at a word's first bit
+    "ends_on_word_bits_63_and_64": lambda rng: (
+        _from_u(rng.integers(0, 64, size=3000)), None),
+    "escape_first": lambda rng: (np.r_[2 ** 20, rng.integers(-8, 8, size=999)], None),
+    "escape_last": lambda rng: (np.r_[rng.integers(-8, 8, size=999), -(2 ** 21)], None),
+    "all_escapes": lambda rng: (_from_u(rng.integers(24, 2 ** 40, size=257)), 0),
+    "u8_plane": lambda rng: (
+        codec.residuals(rng.integers(0, 256, size=(40, 56)).astype(np.uint8), 1), None),
+    "u16_plane": lambda rng: (codec.residuals(
+        rng.integers(0, 2 ** 16, size=(48, 40)).astype(np.uint16), 4), None),
+    "ct_table1_frame": lambda rng: (_ct_frame(rng), None),
+}
+
+
+class TestSpliceCases:
+    """The one splice against the independent bit-array oracle, through the
+    host plan (``rice_encode``) and a plan shaped as the device ships it."""
+
+    @pytest.mark.parametrize("case", sorted(_SPLICE_CASES))
+    def test_splice_matches_legacy_oracle(self, rng, case):
+        res, k = _SPLICE_CASES[case](rng)
+        if k is None:
+            k = _legacy_rice_k(codec._zigzag(res.ravel()))
+            payload, k_host = codec.rice_encode(res)
+            assert (payload, k_host) == _legacy_rice_encode(res)
+        expect, _ = _legacy_rice_encode(res, k)
+        plan = _device_style_plan(res, k)
+        payload, escapes = codec.rice_pack(plan)
+        assert payload == expect
+        assert escapes == int((plan.lens == _QMAX + 2 + 64).sum())
+        ends = np.cumsum(plan.lens, dtype=np.int64)
+        # each case holds what its name claims
+        if case == "empty":
+            assert payload == b"" and codec.rice_encode(res) == (b"", 0)
+        elif case == "all_escapes":
+            assert escapes == res.size
+        elif case in ("escape_first", "escape_last"):
+            at = 0 if case == "escape_first" else -1
+            assert escapes == 1 and plan.lens[at] == _QMAX + 2 + 64
+        elif case == "total_bits_multiple_of_64":
+            assert ends[-1] % 64 == 0 and ends[-1] > 64
+        elif case == "ends_on_word_bits_63_and_64":
+            assert {0, 63} <= set((ends % 64).tolist())
+            assert (ends[:-1] % 64 == 0).any()  # a code starting at bit 0
+        elif case.startswith("k"):
+            assert k == int(case[1:])
+        elif case == "ct_table1_frame":
+            assert res.shape == (512, 512) and escapes > 0
+        if res.size:
+            np.testing.assert_array_equal(
+                codec.rice_decode(payload, k, res.size), res.ravel())
 
 
 class TestVectorizedDecode:
@@ -103,7 +203,7 @@ class TestVectorizedDecode:
             -(2**22), 2**22, size=19
         )
         payload, k = codec.rice_encode(res)
-        assert (codec.rice_plan(res).esc).sum() > 0
+        assert _escapes(res) > 0
         np.testing.assert_array_equal(codec.rice_decode(payload, k, 2048), res)
 
     def test_roundtrip_k_zero_and_empty(self):
@@ -147,7 +247,7 @@ class TestResidualsBatch:
 
 class TestDevicePrepass:
     """Pallas zigzag/rowsum + length/remainder kernels (interpret mode on CPU)
-    must reproduce the host plan bit-exactly — same k, lens, offsets, bytes."""
+    must reproduce the host plan bit-exactly — same k, lens, remainders, bytes."""
 
     def _device_plans(self, res_batch):
         from repro.kernels.jls import entropy
@@ -163,7 +263,7 @@ class TestDevicePrepass:
         u_np = np.asarray(u_d).reshape(N, -1)
         lens_np, rem_np = np.asarray(lens_d), np.asarray(rem_d)
         return [
-            codec.rice_plan_from_prepass(u_np[j], int(ks[j]), lens_np[j], rem_np[j])
+            codec.RicePlan(int(ks[j]), lens_np[j], rem_np[j], u_np[j])
             for j in range(N)
         ]
 
@@ -171,7 +271,7 @@ class TestDevicePrepass:
         from repro.kernels.jls import entropy
 
         assert entropy._QMAX == codec._QMAX == _QMAX
-        assert entropy._ESC_LEN == _QMAX + 2 + 64
+        assert entropy._ESC_LEN == codec._ESC_LEN == _QMAX + 2 + 64
 
     @pytest.mark.parametrize("sv", [1, 3])
     def test_prepass_plan_and_bytes_match_host(self, rng, sv):
@@ -180,8 +280,8 @@ class TestDevicePrepass:
         for plan_d, j in zip(self._device_plans(res), range(3)):
             plan_h = codec.rice_plan(res[j])
             assert plan_d.k == plan_h.k
-            np.testing.assert_array_equal(plan_d.lens, plan_h.lens)
-            np.testing.assert_array_equal(plan_d.offs, plan_h.offs)
+            np.testing.assert_array_equal(plan_d.lens.ravel(), plan_h.lens)
+            np.testing.assert_array_equal(plan_d.rem.ravel(), plan_h.rem)
             assert codec.rice_pack(plan_d) == codec.rice_pack(plan_h)
 
     def test_prepass_escape_lengths_match_host(self, rng):
@@ -192,8 +292,8 @@ class TestDevicePrepass:
         plans_d = self._device_plans(res)
         for j in range(2):
             plan_h = codec.rice_plan(res[j])
-            assert plan_h.esc.sum() > 0
-            np.testing.assert_array_equal(plans_d[j].lens, plan_h.lens)
+            assert _escapes(res[j]) > 0
+            np.testing.assert_array_equal(plans_d[j].lens.ravel(), plan_h.lens)
             assert codec.rice_pack(plans_d[j]) == codec.rice_pack(plan_h)
 
     def test_len_rem_wants_one_k_per_frame(self):
@@ -211,3 +311,74 @@ class TestDevicePrepass:
         res = codec.residuals_batch(imgs, 1)
         for plan_d, j in zip(self._device_plans(res), range(2)):
             assert codec.rice_pack(plan_d) == codec.rice_pack(codec.rice_plan(res[j]))
+
+
+def _spiked_items(rng, n=5, shape=(48, 64)):
+    """u16 frames, flat but for a few isolated spikes, whose residuals code
+    as escapes; each with a banner rect to blank."""
+    items = []
+    for j in range(n):
+        px = (1000 + rng.integers(0, 4, size=shape)).astype(np.uint16)
+        px[rng.integers(8, shape[0], 2 + j), rng.integers(0, shape[1], 2 + j)] = 60000
+        items.append((px, [(4, 0, 24, 6)]))
+    return items
+
+
+def _legacy_escapes(res):
+    u = codec._zigzag(res.ravel())
+    return int(((u >> np.uint64(_legacy_rice_k(u))) > _QMAX).sum())
+
+
+class TestDevicePlanExecutor:
+    """The executor's device-plan path (Pallas pre-pass in interpret mode,
+    then the host splice) against the bit-array oracle, escapes present."""
+
+    def _run(self, items, host_workers=2, tracer=None):
+        from repro.core.batch import BatchedDeidExecutor
+
+        ex = BatchedDeidExecutor(max_batch=4, use_kernel=True, interpret=True,
+                                 host_workers=host_workers, tracer=tracer)
+        try:
+            outs = ex.run([(px.copy(), rl) for px, rl in items], sv=1)
+        finally:
+            ex.close()
+        return outs
+
+    def _blanked_residuals(self, items):
+        from repro.core.batch import blank_inplace
+
+        return [codec.residuals(blank_inplace(px.copy(), rl), 1) for px, rl in items]
+
+    def test_payloads_equal_legacy_oracle(self, rng):
+        items = _spiked_items(rng)
+        outs = self._run(items)
+        for out, res in zip(outs, self._blanked_residuals(items)):
+            assert _legacy_escapes(res) > 0
+            payload, k = _legacy_rice_encode(res)
+            H, W = res.shape
+            header = codec.pack_header(H, W, 16, 1, k, len(payload))
+            assert out.payload == header + payload
+            np.testing.assert_array_equal(codec.decode(out.payload), out.pixels)
+
+    def test_pack_jobs_carry_escape_counts(self, rng):
+        from repro.obs.trace import Tracer
+        from repro.utils.timing import SimClock
+
+        items = _spiked_items(rng)
+        tr = Tracer(SimClock())
+        self._run(items, tracer=tr)
+        got = [s.attrs["escapes"] for s in tr.spans("kernel.pack_job")]
+        assert got == [_legacy_escapes(r) for r in self._blanked_residuals(items)]
+
+    def test_trace_digest_invariant_to_pool_size(self, rng):
+        from repro.obs.trace import Tracer
+        from repro.utils.timing import SimClock
+
+        items = _spiked_items(rng)
+        digests = set()
+        for host_workers in (0, 4):
+            tr = Tracer(SimClock())
+            self._run(items, host_workers=host_workers, tracer=tr)
+            assert len(tr.spans("kernel.pack_job")) == len(items)
+            digests.add(tr.digest())
+        assert len(digests) == 1

@@ -109,15 +109,15 @@ class TestCrashMidOverlap:
     def test_no_partial_batch_escapes(self, rng, monkeypatch):
         items = _mk_items(rng, n=12)
         calls = {"n": 0}
-        real_encode = codec.rice_encode
+        real_pack = codec.rice_pack
 
-        def flaky_encode(res):
+        def flaky_pack(plan):
             calls["n"] += 1
             if calls["n"] == 7:  # mid-run: some chunks already collected
                 raise RuntimeError("entropy coder died mid-overlap")
-            return real_encode(res)
+            return real_pack(plan)
 
-        monkeypatch.setattr(codec, "rice_encode", flaky_encode)
+        monkeypatch.setattr(codec, "rice_pack", flaky_pack)
         ex = BatchedDeidExecutor(
             max_batch=4, use_kernel=False, host_workers=3, pipeline_depth=3
         )
@@ -125,7 +125,7 @@ class TestCrashMidOverlap:
             _run(ex, items)
         # nothing escaped: run() raised instead of returning a partial list,
         # and the executor (and its pool) stays usable for the next study
-        monkeypatch.setattr(codec, "rice_encode", real_encode)
+        monkeypatch.setattr(codec, "rice_pack", real_pack)
         outs = _run(ex, items)
         assert all(o.payload is not None for o in outs)
         ref = BatchedDeidExecutor(max_batch=4, use_kernel=False, host_workers=0)
@@ -137,10 +137,10 @@ class TestCrashMidOverlap:
         # same failure with no pool: identical exception surface
         items = _mk_items(rng, n=6)
 
-        def boom(res):
+        def boom(plan):
             raise RuntimeError("entropy coder died")
 
-        monkeypatch.setattr(codec, "rice_encode", boom)
+        monkeypatch.setattr(codec, "rice_pack", boom)
         ex = BatchedDeidExecutor(max_batch=4, use_kernel=False, host_workers=0)
         with pytest.raises(RuntimeError, match="died"):
             _run(ex, items)
