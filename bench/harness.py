@@ -432,7 +432,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *, t_start: float,
 
     cfg, traffic = cell.config, cell.traffic
     studies = source.make_lake(cfg, traffic, seed)
-    expected = {s.accession: accepted(s) for s in studies}
+    expected = {s.accession: accepted(s, cfg) for s in studies}
     tracer = None
     if trace:
         from repro.obs import Tracer
@@ -560,22 +560,32 @@ class _Profiler:
 
 
 def _warm_up(path: ServedPath, cell: Cell, studies: list, seed: int) -> None:
-    """Compile and warm every padded shape the cell's traffic uses: for each
-    distinct study size, a study cut to one executor chunk of frames (plus
-    its non-image objects) goes through the served path once."""
+    """Compile and warm every padded shape the cell's traffic uses. A study
+    dispatches its instances grouped by pixel shape ((H, W), (H, W, S) or
+    (F, H, W[, S])) in chunks of the executor's batch; for each shape and
+    count of instances, cut to one chunk, not yet warmed, a study of those
+    instances (plus its non-image objects) goes through the served path
+    once."""
     import source
 
     chunk = path.pipeline.executor.max_batch if path.pipeline.executor else 1
     seen = set()
     for i, s in enumerate(studies):
-        frames = [x for x in s.instances if x.pixels is not None]
-        size = min(len(frames), chunk)
-        if size in seen:
+        by_shape: Dict[tuple, list] = {}
+        for x in s.instances:
+            if x.pixels is not None:
+                by_shape.setdefault(x.pixels.shape, []).append(x)
+        picked = []
+        for shape, group in by_shape.items():
+            size = min(len(group), chunk)
+            if (shape, size) not in seen:
+                seen.add((shape, size))
+                picked += group[:size]
+        if not picked:
             continue
-        seen.add(size)
         warm = source.Study(f"WARM-{i}", s.mrn, s.patient_name, s.study_uid, s.study_date,
-                            s.device, s.body_part, frames[:size] + [x for x in s.instances
-                                                                    if x.pixels is None])
+                            s.device, s.body_part, picked + [x for x in s.instances
+                                                             if x.pixels is None])
         path.lake.put_study(warm.accession, source.to_program(warm))
         req = Request(-1 - i, warm, f"WARM-{seed}-{i}", project_key(seed, -1 - i), time.time())
         path.submit(req)

@@ -9,15 +9,24 @@ Golomb-Rice stream the entropy coder must produce.
 
 Layers it covers:
 
-* filter: the paper's categorical exclusions (Discussion items 1-3);
+* filter: the paper's categorical exclusions (Discussion items 1-3), and
+  ultrasound only from the site's whitelist (Table 2), which is the
+  configuration's registered device;
 * header anonymization: DICOM Basic Application Confidentiality Profile with
   Clean Graphics and Retain Longitudinal Temporal Information With Modified
-  Dates, pseudonyms from the project key (HMAC-SHA256);
+  Dates, pseudonyms from the project key (HMAC-SHA256); the Image Pixel and
+  Cine module attributes that decoding a frame needs are kept;
 * rect resolution: the configuration's registered device rects, else the
   text-band detector the configuration states (registry_first);
-* pixel blanking: each rect zeroed, clamped to the frame;
+* pixel blanking: each rect zeroed, clamped to the frame, in every frame and
+  every sample;
 * entropy coder: the stream length of left-neighbour (sv=1) prediction,
-  mod-2^bits residuals, zigzag, one Rice k per frame, escape above QMAX.
+  mod-2^bits residuals, zigzag, one Rice k per plane, escape above QMAX; a
+  colour frame's planes in the multi-plane container, an instance's frames
+  one after another.
+
+Pixel arrays are in DICOM's native order: (H, W) for a mono frame, (H, W, S)
+for a colour one, (F, H, W[, S]) for a multi-frame instance.
 """
 from __future__ import annotations
 
@@ -26,7 +35,7 @@ import datetime as _dt
 import hashlib
 import hmac
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -40,10 +49,18 @@ _REMOVE = ("PatientBirthDate", "PatientBirthTime", "OtherPatientIDs", "OtherPati
            "ReferringPhysicianName", "PhysiciansOfRecord", "PerformingPhysicianName",
            "OperatorsName", "InstitutionName", "InstitutionAddress",
            "InstitutionalDepartmentName", "DeviceSerialNumber", "StationName")
+# PS3.15's Basic Profile lists none of the Image Pixel and Cine module
+# attributes below; a delivered cine or colour frame cannot be decoded
+# without them. The program's anonymizer script removes them today (its
+# ``default remove``), so a multi-frame or colour delivery reads as a header
+# mismatch until it keeps them.
+_PIXEL_MODULE = ("NumberOfFrames", "PhotometricInterpretation", "PlanarConfiguration",
+                 "FrameTime", "CineRate")
 _KEEP = ("PatientSex", "PatientAge", "SeriesNumber", "InstanceNumber", "Modality",
          "Manufacturer", "ManufacturerModelName", "SoftwareVersions", "Rows", "Columns",
          "BitsAllocated", "BitsStored", "SamplesPerPixel", "BurnedInAnnotation", "ImageType",
-         "ConversionType", "BodyPartExamined", "SOPClassUID", "TransferSyntaxUID")
+         "ConversionType", "BodyPartExamined", "SOPClassUID", "TransferSyntaxUID",
+         *_PIXEL_MODULE)
 _JITTER = ("StudyDate", "SeriesDate", "AcquisitionDate", "ContentDate")
 _EMPTY = ("StudyTime", "SeriesTime", "AcquisitionTime", "ContentTime")
 _HASHUID = ("SOPInstanceUID", "StudyInstanceUID", "SeriesInstanceUID")
@@ -59,15 +76,31 @@ def _cs(v) -> str:
     return " ".join(str(v).split()).upper()
 
 
-def filtered(el: Dict[str, object]) -> bool:
+def us_whitelist(config: dict) -> FrozenSet[Tuple[str, str, int, int]]:
+    """The site's ultrasound whitelist as the configuration states it: its
+    registered device, where that is an ultrasound scanner."""
+    if config["modality"] != "US":
+        return frozenset()
+    reg = config["registry_device"]
+    return frozenset({(str(reg["make"]), str(reg["model"]), int(config["rows"]),
+                       int(config["cols"]))})
+
+
+def filtered(el: Dict[str, object],
+             whitelist: FrozenSet[Tuple[str, str, int, int]] = frozenset()) -> bool:
     """The paper's exclusions: film digitizers, documents and reports,
     presentation states, raw/other modalities, secondary captures and
     declared burned-in annotation (unless from a trusted station), empty
-    conversion type, derived images (unless CT/MR localizers), video, and
-    objects without pixel geometry. Ultrasound needs the site's whitelist,
-    which this reference does not hold, so it refuses it."""
+    conversion type, derived images (unless CT/MR localizers), video,
+    ultrasound from a device variant (make, model, rows, columns) missing
+    from ``whitelist``, and objects without pixel geometry."""
     if el.get("Modality") == "US":
-        raise ValueError("the reference holds no ultrasound whitelist")
+        if "Rows" not in el or "Columns" not in el:
+            return True
+        variant = (str(el.get("Manufacturer", "")), str(el.get("ManufacturerModelName", "")),
+                   int(el["Rows"]), int(el["Columns"]))
+        if variant not in whitelist:
+            return True
     sop = str(el.get("SOPClassUID", ""))
     mod = _cs(el.get("Modality", "")) if "Modality" in el else None
     trusted = str(el.get("StationName", "")).startswith("SAFE")
@@ -169,7 +202,10 @@ def detector_rects(px: np.ndarray, bits_stored: int, det: dict) -> List[Rect]:
     """Full-width bands over rows whose glyph hits (pixels at or above
     binarize_frac of the stored ceiling) reach row_frac of the width; runs
     shorter than min_band_rows are dropped, survivors padded by pad_rows,
-    clipped to the frame and merged where they touch."""
+    clipped to the frame and merged where they touch. Single-frame mono
+    planes only: the configurations send no other kind through it."""
+    if px.ndim != 2:
+        raise ValueError(f"the detector reads one mono plane, got shape {px.shape}")
     H, W = px.shape
     thresh = np.float32(((1 << bits_stored) - 1) * float(det["binarize_frac"]))
     hits = (px.astype(np.float32) >= thresh).sum(axis=1)
@@ -192,11 +228,18 @@ def detector_rects(px: np.ndarray, bits_stored: int, det: dict) -> List[Rect]:
     return [(0, a, W, b - a) for a, b in bands]
 
 
-def blank(px: np.ndarray, rects: Sequence[Rect]) -> np.ndarray:
+def blank(px: np.ndarray, rects: Sequence[Rect], samples: int = 1) -> np.ndarray:
+    """``px`` with each rect zeroed in every frame and every sample: rows and
+    columns are the last two axes of a mono array, the two before the
+    samples of a colour one."""
     out = px.copy()
-    H, W = out.shape
+    rows = out.ndim - (3 if samples > 1 else 2)
+    H, W = out.shape[rows], out.shape[rows + 1]
     for x, y, w, h in rects:
-        out[max(0, y) : max(0, min(H, y + h)), max(0, x) : max(0, min(W, x + w))] = 0
+        at = [slice(None)] * out.ndim
+        at[rows] = slice(max(0, y), max(0, min(H, y + h)))
+        at[rows + 1] = slice(max(0, x), max(0, min(W, x + w)))
+        out[tuple(at)] = 0
     return out
 
 
@@ -232,6 +275,20 @@ def coded_bytes(planes: np.ndarray, coder: dict) -> List[int]:
     return out
 
 
+def coded_length(px: np.ndarray, samples: int, coder: dict) -> int:
+    """Bytes of one instance's stream: its frames' streams one after another.
+    A mono frame is one plane stream. A colour frame is the multi-plane
+    container: a header of ``multi_plane_header_bytes`` (magic, kind, plane
+    count), then for each sample plane a length word of
+    ``plane_length_bytes`` and that plane's stream, with a Rice k of its own."""
+    if samples == 1:
+        return sum(coded_bytes(px.reshape((-1,) + px.shape[-2:]), coder))
+    frames = px.reshape((-1,) + px.shape[-3:])
+    planes = np.moveaxis(frames, -1, 1).reshape((-1,) + frames.shape[1:3])
+    return (len(frames) * int(coder["multi_plane_header_bytes"])
+            + sum(int(coder["plane_length_bytes"]) + n for n in coded_bytes(planes, coder)))
+
+
 # ---------------------------------------------------------------- per study
 @dataclass
 class Expected:
@@ -256,17 +313,19 @@ def expected_uids(study, p: Pseudonyms, config: dict, keep: Sequence[bool]) -> L
             for inst, k in zip(study.instances, keep) if k]
 
 
-def accepted(study) -> List[bool]:
-    return [not filtered(i.elements) for i in study.instances]
+def accepted(study, config: dict) -> List[bool]:
+    wl = us_whitelist(config)
+    return [not filtered(i.elements, wl) for i in study.instances]
 
 
 def expect_study(study, p: Pseudonyms, config: dict) -> List[Expected]:
     """What the configuration says each accepted instance of ``study`` must
     become, in source order."""
     coder = config["coder"]
+    samples = int(config.get("samples_per_pixel", 1))
     out: List[Expected] = []
     rects = registry_rects(config, study.device)
-    for inst, keep in zip(study.instances, accepted(study)):
+    for inst, keep in zip(study.instances, accepted(study, config)):
         if not keep:
             continue
         el = dict(inst.elements)
@@ -276,8 +335,8 @@ def expect_study(study, p: Pseudonyms, config: dict) -> List[Expected]:
             el["TransferSyntaxUID"] = coder["transfer_syntax"]
         header, actions = anonymize(el, bool(inst.private), p, config["pseudonyms"]["uid_root"])
         out.append(Expected(str(header["SOPInstanceUID"]), header, actions,
-                            blank(inst.pixels, r), None))
+                            blank(inst.pixels, r, samples), None))
     if coder["recompress"]:
         for e in out:
-            e.coded = coded_bytes(e.pixels[None], coder)[0]
+            e.coded = coded_length(e.pixels, samples, coder)
     return out

@@ -8,6 +8,14 @@ banners (strokes at the stored-sample ceiling) in the device's PHI regions,
 and the non-image objects a real study carries (structured reports,
 presentation states, secondary captures), which the filter must drop.
 
+A configuration may also state colour pixels (``samples_per_pixel`` 3,
+``photometric`` ``RGB``, interleaved; unstated, MONOCHROME2) and multi-frame cine loops beside the
+single-frame instances (``cine_per_study``: each study's loop lengths).
+Pixel arrays take DICOM's native order: (H, W) for a mono frame, (H, W, S)
+for a colour one, (F, H, W[, S]) for a cine. Colour planes and cine loops
+draw from random streams of their own, so a configuration without these keys
+makes the same studies, value for value, as one written before them.
+
 Studies are plain data (:class:`Study`, :class:`Instance`); ``to_program``
 wraps one in the program's own dataset types for the source store. The
 reference (``reference.py``) reads only the plain form.
@@ -16,7 +24,7 @@ from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -26,6 +34,7 @@ _FIRST = ["JANE", "JOHN", "MARIA", "WEI", "PRIYA", "OMAR", "SOFIA", "LIAM"]
 _LAST = ["DOE", "SMITH", "GARCIA", "CHEN", "PATEL", "HASSAN", "ROSSI", "KIM"]
 _SOP_CLASS = {"CT": "1.2.840.10008.5.1.4.1.1.2", "DX": "1.2.840.10008.5.1.4.1.1.1.1",
               "MR": "1.2.840.10008.5.1.4.1.1.4", "US": "1.2.840.10008.5.1.4.1.1.6.1"}
+_MULTIFRAME_SOP_CLASS = {"US": "1.2.840.10008.5.1.4.1.1.3.1"}  # Ultrasound Multi-frame Image
 _BODY_PARTS = {"CT": ["CHEST", "ABDOMEN", "HEAD", "PELVIS"], "DX": ["CHEST", "HAND", "FOOT", "SPINE"]}
 # non-image or derived objects a study carries beside its frames
 _EXTRA = {
@@ -102,23 +111,45 @@ def _frames(rng, n: int, H: int, W: int, dtype, maxv: int) -> np.ndarray:
     return out
 
 
+def _colour(rng, luma: np.ndarray, samples: int, maxv: int) -> np.ndarray:
+    """(n, H, W) luma to (n, H, W, samples): each sample is the luma with a
+    low-frequency tint of its own, drawn from ``rng`` (a colour stream)."""
+    n, H, W = luma.shape
+    out = np.empty((n, H, W, samples), luma.dtype)
+    for c in range(samples):
+        for c0 in range(0, n, _CHUNK):
+            m = min(_CHUNK, n - c0)
+            tint = rng.random((m, -(-H // 16), -(-W // 16)), dtype=np.float32)
+            tint = np.repeat(np.repeat(tint, 16, axis=1), 16, axis=2)[:, :H, :W]
+            tint *= 0.15 * maxv
+            tint += luma[c0 : c0 + m] * np.float32(0.85)
+            out[c0 : c0 + m, :, :, c] = tint.astype(luma.dtype)
+    return out
+
+
 def _burn(img: np.ndarray, rect: Rect, rng, maxv: int) -> None:
+    """Text strokes at ``maxv`` in ``rect`` of one frame, (H, W) or
+    (H, W, S): every sample of a stroke pixel alike."""
     x, y, w, h = rect
-    H, W = img.shape
+    H, W = img.shape[:2]
     x2, y2 = min(x + w, W), min(y + h, H)
     if x >= x2 or y >= y2:
         return
     region = img[y:y2, x:x2]
-    strokes = (np.arange(region.shape[1]) // 3) % 2 == 0
-    mask = np.broadcast_to(strokes, region.shape) & (rng.random(region.shape) < 0.85)
+    shape = region.shape[:2]
+    strokes = (np.arange(shape[1]) // 3) % 2 == 0
+    mask = np.broadcast_to(strokes, shape) & (rng.random(shape) < 0.85)
     region[~mask] = (region[~mask] * 0.1).astype(img.dtype)
     region[mask] = maxv
 
 
-def make_study(config: dict, scanner: str, seed: int, index: int, n_frames: int) -> Study:
+def make_study(config: dict, scanner: str, seed: int, index: int, n_frames: int,
+               cines: Sequence[int] = ()) -> Study:
     """One study of ``config``'s geometry. ``scanner`` is ``"registry"`` (the
     configuration's registered device) or ``"unknown"`` (a scanner the site
-    registry does not hold, made from the seed)."""
+    registry does not hold, made from the seed). ``n_frames`` counts the
+    single-frame instances; ``cines`` lists the frame counts of the study's
+    multi-frame instances, which follow them."""
     rng = _rng(seed, index, 1)
     mod = str(config["modality"])
     H, W = int(config["rows"]), int(config["cols"])
@@ -144,22 +175,41 @@ def make_study(config: dict, scanner: str, seed: int, index: int, n_frames: int)
                   parts[int(rng.integers(len(parts)))])
     series_uid = _uid(f"series/{accession}")
     every = int(config.get("burned_in_every", 1))
+    samples = int(config.get("samples_per_pixel", 1))
+    colour = _rng(seed, index, 2)
     pixels = _frames(rng, n_frames, H, W, dtype, maxv)
+    if samples > 1:
+        pixels = _colour(colour, pixels, samples, maxv)
     for i in range(n_frames):
         if i % every == 0:
             for rect in burn:
                 _burn(pixels[i], rect, rng, maxv)
-        study.instances.append(_instance(study, series_uid, i, rng, dtype, pixels[i]))
+        study.instances.append(_instance(config, study, series_uid, i, rng, dtype, pixels[i]))
+    for j, n in enumerate(cines):
+        # a loop's frames, burns and identity draw from a stream of their own
+        crng = _rng(seed, index, 3, j)
+        loop = _frames(crng, int(n), H, W, dtype, maxv)
+        if samples > 1:
+            loop = _colour(_rng(seed, index, 2, 1 + j), loop, samples, maxv)
+        for frame in loop:
+            for rect in burn:
+                _burn(frame, rect, crng, maxv)
+        study.instances.append(_instance(config, study, series_uid, n_frames + j, crng, dtype, loop))
+    first_extra = n_frames + len(cines)
     for j, kind in enumerate(config.get("extra_instances", [])):
         img = None if kind in _NO_PIXELS else _frames(rng, 1, H, W, dtype, maxv)[0]
-        inst = _instance(study, series_uid, n_frames + j, rng, dtype, img)
+        if img is not None and samples > 1:
+            img = _colour(colour, img[None], samples, maxv)[0]
+        inst = _instance(config, study, series_uid, first_extra + j, rng, dtype, img)
         inst.elements.update(_EXTRA[kind])
         study.instances.append(inst)
     return study
 
 
-def _instance(study: Study, series_uid: str, idx: int, rng, dtype, pixels) -> Instance:
+def _instance(config: dict, study: Study, series_uid: str, idx: int, rng, dtype,
+              pixels) -> Instance:
     d = study.device
+    samples = int(config.get("samples_per_pixel", 1))
     el: Dict[str, object] = {
         "SOPClassUID": _SOP_CLASS[str(d["modality"])],
         "SOPInstanceUID": _uid(f"{study.accession}/{idx}"),
@@ -194,26 +244,40 @@ def _instance(study: Study, series_uid: str, idx: int, rng, dtype, pixels) -> In
         "Columns": d["cols"],
         "BitsAllocated": 16 if dtype == np.uint16 else 8,
         "BitsStored": 12 if dtype == np.uint16 else 8,
-        "SamplesPerPixel": 1,
+        "SamplesPerPixel": samples,
         "BurnedInAnnotation": "NO",
         "ImageType": "ORIGINAL\\PRIMARY\\AXIAL",
         "SeriesDescription": f"{d['modality']} series",
         "StudyDescription": f"{d['modality']} study for MRN {study.mrn}",
         "PatientComments": f"Patient {study.patient_name} seen by Dr. House",
     }
+    if "photometric" in config:  # unstated: MONOCHROME2, and no element written
+        el["PhotometricInterpretation"] = str(config["photometric"])
+    if samples > 1:
+        el["PlanarConfiguration"] = 0  # samples interleaved: (H, W, S)
+    multi_frame = pixels is not None and pixels.ndim == (4 if samples > 1 else 3)
+    if multi_frame:
+        el["SOPClassUID"] = _MULTIFRAME_SOP_CLASS[str(d["modality"])]
+        el["NumberOfFrames"] = int(pixels.shape[0])
+        rate = int(config["cine_rate"])
+        el["CineRate"] = rate
+        el["FrameTime"] = round(1000.0 / rate, 3)  # ms between frames
     private = {"(0009,0010)": "VENDOR PRIVATE CREATOR", "(0009,1001)": f"internal-id-{study.mrn}"}
     return Instance(el, private, pixels)
 
 
 def make_lake(config: dict, traffic: dict, seed: int) -> List[Study]:
-    """The cell's distinct source studies. Frame counts are fixed by the
-    configuration, so every seed serves the same sizes; the seed changes the
-    pixels, the identities and, for unknown scanners, the devices."""
+    """The cell's distinct source studies. Frame and loop counts are fixed by
+    the configuration, so every seed serves the same sizes; the seed changes
+    the pixels, the identities and, for unknown scanners, the devices."""
     counts = [int(n) for n in config["frames_per_study"]]
-    if len(counts) != int(config["studies"]):
-        raise ValueError(f"{config['name']}: frames_per_study lists {len(counts)} studies, "
-                         f"studies says {config['studies']}")
-    return [make_study(config, traffic["scanner"], seed, i, n) for i, n in enumerate(counts)]
+    cines = [[int(f) for f in c] for c in config.get("cine_per_study", [[] for _ in counts])]
+    for key, got in (("frames_per_study", len(counts)), ("cine_per_study", len(cines))):
+        if got != int(config["studies"]):
+            raise ValueError(f"{config['name']}: {key} lists {got} studies, "
+                             f"studies says {config['studies']}")
+    return [make_study(config, traffic["scanner"], seed, i, n, c)
+            for i, (n, c) in enumerate(zip(counts, cines))]
 
 
 def to_program(study: Study):

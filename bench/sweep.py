@@ -39,7 +39,7 @@ def main(argv=None) -> int:
     from stats import quantile
 
     studies = source.make_lake(cell.config, cell.traffic, args.seed)
-    expected = {s.accession: accepted(s) for s in studies}
+    expected = {s.accession: accepted(s, cell.config) for s in studies}
     cell.traffic = dict(cell.traffic, check_sample=0)
     with tempfile.TemporaryDirectory(prefix="bench-sweep-") as tmp:
         path = harness.ServedPath(cell, Path(tmp), None, None)

@@ -117,3 +117,31 @@ def test_fault_in_the_timed_path_is_not_correct(fault, broken):
     r = run(tiny_cell("export_cold"), prepare=fault)
     assert not r["correct"]
     assert r["checks"][broken]["value"] > 0
+
+
+def _blind_detector(path):
+    """Fault: the detector pass reports no glyph hits for the first slice
+    of every dispatch, so that slice's burned-in bands are never found."""
+    ex = path.pipeline.executor
+    row_hits = ex.detect_row_hits
+
+    def blind(entries, **kw):
+        hits = row_hits(entries, **kw)
+        if hits:
+            hits[0] = np.zeros_like(hits[0])
+        return hits
+
+    ex.detect_row_hits = blind
+
+
+@pytest.mark.parametrize("fault,broken", [
+    (_identity_anonymizer, "requests_incomplete"),
+    (_half_delivered, "requests_incomplete"),
+    (_altered_pixels, "pixel_mismatches"),
+    (_blind_detector, "pixel_mismatches"),
+])
+def test_fault_in_the_detector_path_is_not_correct(fault, broken):
+    """The unregistered-scanner cell's path, the detector pre-pass with it."""
+    r = run(tiny_cell("export_unknown"), prepare=fault)
+    assert not r["correct"]
+    assert r["checks"][broken]["value"] > 0

@@ -12,12 +12,15 @@ import source
 
 DATA = Path(__file__).resolve().parent / "data"
 CFG = json.loads((DATA / "tiny_ct.json").read_text())
+US_CODER = json.loads((DATA / "tiny_us.json").read_text())["coder"]
 
 
 @pytest.mark.parametrize("shape,dtype,full_range", [
     ((64, 128), np.uint16, False),
     ((64, 128), np.uint16, True),   # noise across the whole range: escape codes
     ((48, 64), np.uint8, True),
+    ((480, 640, 3), np.uint8, True),     # a colour still: the multi-plane container
+    ((4, 480, 640, 3), np.uint8, True),  # a colour cine: its frames' containers
 ])
 def test_coded_bytes_is_the_program_codec_length(shape, dtype, full_range):
     from repro.dicom import codec
@@ -25,7 +28,12 @@ def test_coded_bytes_is_the_program_codec_length(shape, dtype, full_range):
     rng = np.random.default_rng(3)
     hi = np.iinfo(dtype).max + 1 if full_range else 4096
     px = rng.integers(0, hi, shape).astype(dtype)
-    assert reference.coded_bytes(px[None], CFG["coder"]) == [len(codec.encode(px, 1))]
+    if len(shape) == 2:
+        assert reference.coded_bytes(px[None], CFG["coder"]) == [len(codec.encode(px, 1))]
+        assert reference.coded_length(px, 1, CFG["coder"]) == len(codec.encode(px, 1))
+    else:
+        frames = px if len(shape) == 4 else px[None]
+        assert reference.coded_length(px, 3, US_CODER) == sum(len(codec.encode(f, 1)) for f in frames)
 
 
 def test_detector_rects_are_the_program_detector_bands():

@@ -2,10 +2,14 @@
 recorded span lists: shares of the window, the copy rate and the pack's
 cores. Each reads nothing, and returns None, where the program has no such
 span."""
+import json
+from pathlib import Path
+
 import pytest
 
 import harness
 from repro.obs.trace import Span
+from roofline import detect_bytes, fused_bytes
 
 SHARES = {
     "device_wait_share.export": "kernel.device_wait",
@@ -14,15 +18,17 @@ SHARES = {
     "filter_share.export": "pipeline.filter",
     "anonymize_share.export": "pipeline.anonymize",
     "lake_put_share.export": "pipeline.lake_put",
+    "detect_share.export": "kernel.detect_dispatch",
 }
-NEW = sorted(SHARES) + ["d2h_GBps.export", "pack_cores.export"]
+NEW = sorted(SHARES) + ["d2h_GBps.export", "pack_cores.export",
+                       "detect_roofline_pct.export"]
 
 
-def _run(spans, t0=10.0, t1=20.0):
+def _run(spans, t0=10.0, t1=20.0, device_events=()):
     cell = harness.Cell("ct_export_cold", {}, {"loop": "closed"}, 1, [], [])
     recorded = [Span("t", f"s{i:08d}", None, name, a, b, seq=i, attrs=attrs)
                 for i, (name, a, b, attrs) in enumerate(spans, 1)]
-    return harness.Run(cell, t0, t1, [], recorded)
+    return harness.Run(cell, t0, t1, [], recorded, list(device_events), "TPU v5 lite")
 
 
 def _read(metric, run):
@@ -61,6 +67,48 @@ def test_pack_cores_needs_cpu_seconds():
     # a simulated clock leaves cpu_s out: nothing to read
     run = _run([("kernel.pack_job", 11.0, 12.0, {})])
     assert _read("pack_cores.export", run) is None
+
+
+def _detector_trace():
+    """The recorded v5e trace's detector pass: 32 slices of 512x512 u16 in
+    one ``_profiles`` call, placed at 102.497 s on the host clock."""
+    d = json.loads((Path(__file__).resolve().parent / "data" / "trace_small.json").read_text())
+    return [tuple(e) for e in d["device_events"]]
+
+
+def _detect_span(path="textdetect", batch=32):
+    return ("kernel.detect_dispatch", 102.49, 102.50,
+            {"path": path, "batch": batch, "shape": "512x512", "dtype": "uint16"})
+
+
+def test_detect_roofline_is_needed_bytes_over_kernel_time():
+    events = _detector_trace()
+    secs = sum(b - a for ln, nm, a, b in events if ln == "XLA Ops" and nm.startswith("%_profiles."))
+    run = _run([_detect_span()], t0=100.0, t1=103.0, device_events=events)
+    want = 100.0 * detect_bytes(32, "512x512", "uint16") / 819e9 / secs
+    assert _read("detect_roofline_pct.export", run) == pytest.approx(want)
+    assert 0 < want < 50  # a memory-bound reduction at one slice a row of the batch
+
+
+@pytest.mark.parametrize("spans,with_trace", [
+    ([], True),                            # no kernel.detect_dispatch span
+    ([_detect_span(path="oracle")], True),  # the host oracle ran, not the kernel
+    ([_detect_span()], False),             # no device trace
+])
+def test_detect_roofline_silent_without_its_spans_or_kernel(spans, with_trace):
+    run = _run(spans, t0=100.0, t1=103.0, device_events=_detector_trace() if with_trace else ())
+    assert _read("detect_roofline_pct.export", run) is None
+
+
+def test_detect_bytes_count_real_frames_at_pixel_width():
+    assert detect_bytes(32, "512x512", "uint16") == 32 * (512 * 512 * 2 + 512 * 4)
+    assert detect_bytes(3, "2500x2048", "uint16") == 3 * (2500 * 2048 * 2 + 2500 * 4)
+    assert detect_bytes(2, "480x640x3", "uint8") == 2 * (480 * 640 * 3 + 480 * 4)
+
+
+def test_fused_bytes_of_a_colour_frame_is_its_samples():
+    assert fused_bytes(1, "480x640x3", "uint8") == 480 * 640 * 3 * 2
+    assert fused_bytes(5, "480x640", "uint8") == 5 * 480 * 640 * 2
 
 
 @pytest.mark.parametrize("metric", NEW)
