@@ -65,6 +65,15 @@ def test_traced_run_reports_span_metrics_and_breakdown():
     assert r["device"]["window_s"] > 0
 
 
+def test_traced_open_loop_run_reads_the_queue_wait():
+    """Open-loop requests, traced: each ``pipeline.run_study`` span is found
+    under its request's ``worker.process`` span, so the wait reads a number."""
+    r = run(tiny_cell("ondemand_poisson", per_layer=("queue_wait_ms.ondemand",),
+                      rate_per_s=2.0, check_sample=2), trace=True)
+    assert r["correct"], r["checks"]
+    assert r["metrics"]["queue_wait_ms.ondemand"]["value"] >= 0
+
+
 @pytest.mark.parametrize("traffic_name,control,broken", [
     ("export_cold", "no_recompress", "coder_mismatches"),
     ("export_unknown", "detector_off", "pixel_mismatches"),

@@ -24,11 +24,14 @@ NEW = sorted(SHARES) + ["d2h_GBps.export", "pack_cores.export",
                        "detect_roofline_pct.export"]
 
 
-def _run(spans, t0=10.0, t1=20.0, device_events=()):
-    cell = harness.Cell("ct_export_cold", {}, {"loop": "closed"}, 1, [], [])
-    recorded = [Span("t", f"s{i:08d}", None, name, a, b, seq=i, attrs=attrs)
-                for i, (name, a, b, attrs) in enumerate(spans, 1)]
-    return harness.Run(cell, t0, t1, [], recorded, list(device_events), "TPU v5 lite")
+def _run(spans, t0=10.0, t1=20.0, device_events=(), loop="closed", requests=()):
+    """A window over ``spans``, each ``(name, t0, t1, attrs)`` or, nested,
+    ``(name, t0, t1, attrs, parent)`` with the parent's 1-based position."""
+    cell = harness.Cell("ct_export_cold", {}, {"loop": loop}, 1, [], [])
+    recorded = [Span("t", f"s{i:08d}", f"s{s[4]:08d}" if len(s) > 4 else None, s[0], s[1], s[2],
+                     seq=i, attrs=s[3])
+                for i, s in enumerate(spans, 1)]
+    return harness.Run(cell, t0, t1, list(requests), recorded, list(device_events), "TPU v5 lite")
 
 
 def _read(metric, run):
@@ -106,6 +109,26 @@ def test_detect_bytes_count_real_frames_at_pixel_width():
     assert detect_bytes(2, "480x640x3", "uint8") == 2 * (480 * 640 * 3 + 480 * 4)
 
 
+def _fused_dispatch(batch, shape):
+    """A fused-path dispatch of ``batch`` u8 frames of ``shape``, with one
+    ``_fused`` custom call of 2 ms on the device."""
+    event = ("XLA Ops", "%_fused.1 = u8[32,480,640]{2,1,0} custom-call(u8[32,480,640]{2,1,0} %p)",
+             12.0, 12.002)
+    return _run([("kernel.dispatch", 11.9, 12.1,
+                  {"path": "fused", "batch": batch, "shape": shape, "dtype": "uint8"})],
+                device_events=[event])
+
+
+def test_fused_roofline_counts_colour_planes_and_frames_alike():
+    """Thirty planes of 480x640 and ten frames of 480x640x3 are the same
+    real samples, and so the same needed bytes: a dispatch's ``batch`` times
+    its ``shape`` counts the samples dispatched, padding left out."""
+    planes = _read("fused_roofline_pct.export", _fused_dispatch(30, "480x640"))
+    frames = _read("fused_roofline_pct.export", _fused_dispatch(10, "480x640x3"))
+    assert planes == pytest.approx(frames)
+    assert planes == pytest.approx(100.0 * 30 * 480 * 640 * 2 / 819e9 / 0.002)
+
+
 def test_fused_bytes_of_a_colour_frame_is_its_samples():
     assert fused_bytes(1, "480x640x3", "uint8") == 480 * 640 * 3 * 2
     assert fused_bytes(5, "480x640", "uint8") == 5 * 480 * 640 * 2
@@ -116,6 +139,31 @@ def test_silent_on_a_program_without_the_spans(metric):
     run = _run([("pipeline.run_study", 11.0, 19.0, {}),
                 ("kernel.entropy_code", 12.0, 14.0, {"wait_s": 0.5})])
     assert _read(metric, run) is None
+
+
+def _request(key, due):
+    return harness.Request(0, None, "P", b"", due, journal_key=key)
+
+
+def _queued(loop):
+    """Two requests, each under ``worker.process`` → ``worker.deid`` →
+    ``pipeline.run_study``, waiting 0.5 s and 1.5 s; a warm-up study's
+    spans, with no request, beside them."""
+    spans = []
+    for key, start in (("P1/A1", 12.5), ("P2/A2", 14.5), ("WARM/A0", 10.5)):
+        n = len(spans)
+        spans += [("worker.process", start - 0.1, start + 1.0, {"key": key}),
+                  ("worker.deid", start - 0.05, start + 0.9, {}, n + 1),
+                  ("pipeline.run_study", start, start + 0.8, {}, n + 2)]
+    return _run(spans, loop=loop, requests=[_request("P1/A1", 12.0), _request("P2/A2", 13.0)])
+
+
+def test_queue_wait_walks_up_to_the_worker_process_span():
+    assert _read("queue_wait_ms.ondemand", _queued("open")) == pytest.approx(1000.0)
+
+
+def test_queue_wait_is_silent_on_a_closed_loop():
+    assert _read("queue_wait_ms.ondemand", _queued("closed")) is None
 
 
 @pytest.mark.usefixtures("jax_config_restored")

@@ -1,9 +1,10 @@
 """Ultrasound cine and colour studies (``us_table1``) through the source
 generator and the plain reference: the filter's whitelist, blanking of every
 frame and sample, and the check's comparisons, which must count a fault
-planted in a delivery. The program serves no such study yet, so the
-deliveries here are built from the reference's own expectation, with their
-stream lengths from the program's codec frame by frame."""
+planted in a delivery. The deliveries in these tests are built from the
+reference's expectation, with their stream lengths from the program's codec
+frame by frame, so they hold whether or not the program serves such studies
+and whether or not ``BENCHMARK.json`` has a cell of them."""
 import json
 from pathlib import Path
 from types import SimpleNamespace
@@ -17,8 +18,32 @@ import source
 
 DATA = Path(__file__).resolve().parent / "data"
 TINY = json.loads((DATA / "tiny_us.json").read_text())
-US = json.loads((harness.BENCH / "configs" / "us_table1.json").read_text())
+SPEC = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
+FILE = "bench/configs/us_table1.json"
 SEED = 4_000_000_033
+
+
+def us_config(spec: dict, root: Path = harness.ROOT) -> dict:
+    """``us_table1`` as ``spec`` under ``root`` has it: where ``spec`` lists
+    the configuration, its entry names the file ``FILE`` and carries that
+    file's own ``source`` and ``reduced``; where it does not, the file alone."""
+    cfg = json.loads((root / FILE).read_text())
+    entry = {c["name"]: c for c in spec["configs"]}.get("us_table1")
+    if entry is not None:
+        assert entry["file"] == FILE
+        assert (entry["source"], entry["reduced"]) == (cfg["source"], cfg["reduced"])
+    return cfg
+
+
+def check_table1_us_row(spec: dict, root: Path = harness.ROOT) -> None:
+    """``us_table1`` is Table 1's US row, with or without a cell of it."""
+    us = us_config(spec, root)
+    per_frame = us["rows"] * us["cols"] * us["samples_per_pixel"]
+    assert per_frame == 921_600
+    for still, loops in zip(us["frames_per_study"], us["cine_per_study"]):
+        assert still + sum(loops) == 380  # 350 MB a study
+        assert all(72 <= n <= 120 for n in loops)  # 2.4-4 s at 30 frames/s
+    assert us["reduced"] == ["studies"] and us["guarantees"] and us["assumed"]
 
 
 def _lake(scanner="registry"):
@@ -26,21 +51,15 @@ def _lake(scanner="registry"):
 
 
 def test_us_table1_is_the_table1_us_row():
-    per_frame = US["rows"] * US["cols"] * US["samples_per_pixel"]
-    assert per_frame == 921_600
-    for still, loops in zip(US["frames_per_study"], US["cine_per_study"]):
-        assert still + sum(loops) == 380  # 350 MB a study
-        assert all(72 <= n <= 120 for n in loops)  # 2.4-4 s at 30 frames/s
-    assert US["reduced"] == ["studies"] and US["guarantees"] and US["assumed"]
-    spec = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
-    assert "us_table1" not in {c["name"] for c in spec["configs"]}
+    check_table1_us_row(SPEC)
 
 
 def test_us_table1_device_is_whitelisted_with_the_registry_rects():
     from repro.dicom.devices import DeviceKey, registry
 
-    reg = US["registry_device"]
-    key = DeviceKey("US", reg["make"], reg["model"], US["rows"], US["cols"])
+    us = us_config(SPEC)
+    reg = us["registry_device"]
+    key = DeviceKey("US", reg["make"], reg["model"], us["rows"], us["cols"])
     assert registry().us_whitelisted(key)
     assert [list(r) for r in registry().scrub_rects(key)] == reg["rects"]
 
