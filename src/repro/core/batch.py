@@ -20,21 +20,23 @@ wants:
   issued (jax dispatch is asynchronous) before the host entropy tail of
   chunk N is drained, so device and host work overlap instead of
   serializing. On the kernel path the device also runs the Golomb-Rice
-  *plan* pre-pass (``kernels/jls/entropy``: zigzag + row sums, then
-  per-symbol code lengths + remainders), leaving the host only the final
-  splice (``codec.rice_pack``), which reads those lengths and remainders
-  as they arrive.
-* **host tail** — per-instance pack/encode jobs are embarrassingly parallel
-  and fan out across a small thread pool (numpy releases the GIL); jobs are
-  pure functions of per-instance arrays and are drained in submission
-  order, so payload bytes are identical for any pool size — including the
-  inline ``host_workers=0`` mode.
+  coder (``kernels/jls/entropy``: zigzag + row sums, then per-symbol code
+  lengths + remainders, then the splice), and only each frame's payload
+  crosses back; the host picks ``k`` from the row sums in between.
+* **host tail** — per-instance pack/encode jobs (the host paths, and a
+  device-plan frame whose code outgrows its raw size) are embarrassingly
+  parallel and fan out across a small thread pool (numpy releases the GIL);
+  jobs are pure functions of per-instance arrays and are drained in
+  submission order, so payload bytes are identical for any pool size —
+  including the inline ``host_workers=0`` mode.
 * **spans** — under ``kernel.entropy_code`` the collect phase records where
   the time goes: ``kernel.device_wait`` (blocking on the chunk's device
   outputs), ``kernel.d2h`` (each device->host copy, with its ``bytes``) and
-  one ``kernel.pack_job`` per frame with its ``escapes`` count, timed on
-  the pool thread that ran it and entered by the collector in chunk order
-  (DESIGN.md §11).
+  one ``kernel.pack_job`` per host-packed frame with its ``escapes`` count,
+  timed on the pool thread that ran it and entered by the collector in
+  chunk order (DESIGN.md §11). The span itself counts the chunk's
+  ``escapes`` and the frames each route coded (``device_packed``,
+  ``host_packed``).
 
 The executor owns dispatch statistics and a lazily created pack pool; one
 instance can serve every stage/pipeline combination and is safe to share
@@ -499,10 +501,11 @@ class BatchedDeidExecutor:
                 out[i] = BatchOutput(pixels=pixels)
             return
 
-        # recompress paths: the host Golomb-Rice tail — its own span so a
-        # trace shows the host/device boundary (queue_s = how long the chunk
-        # sat in flight behind newer dispatches, wait_s = device syncs and
-        # copies, the k loop and the _len_rem dispatch)
+        # recompress paths: the Golomb-Rice tail — its own span so a trace
+        # shows the host/device boundary (queue_s = how long the chunk sat
+        # in flight behind newer dispatches, wait_s = device syncs and
+        # copies, the k loop and the device splice, up to the payload's
+        # arrival)
         # NB: pool size / pipeline depth are deliberately NOT span attrs —
         # the trace digest must be identical for any host_workers setting
         with self.tracer.span(
@@ -510,29 +513,7 @@ class BatchedDeidExecutor:
         ) as sp:
             t0 = clk.now() if clk is not None else None
             if st.kind == "device_plan":
-                from repro.kernels.jls import entropy
-
-                self._wait(st.rs, st.u)
-                rs = self._d2h("rs", st.rs)
-                # one k per padded frame: padding frames get k=0 and are
-                # never packed, but the kernel reads a k for every frame
-                ks = np.zeros(st.u.shape[0], np.int32)
-                for j in range(len(chunk)):
-                    ks[j] = codec._rice_k_from_sum(int(rs[j].sum()), H * W)
-                lens_d, rem_d = entropy.rice_len_rem(
-                    st.u, ks, bh=self.bh, interpret=self.interpret
-                )
-                u_np = self._d2h("u", st.u)
-                self._wait(lens_d, rem_d)
-                lens_np, rem_np = self._d2h("lens", lens_d), self._d2h("rem", rem_d)
-                st.jobs = self._submit_jobs(
-                    [
-                        lambda j=j: _pack(
-                            codec.RicePlan(int(ks[j]), lens_np[j], rem_np[j], u_np[j])
-                        )
-                        for j in range(len(chunk))
-                    ]
-                )
+                coded, ks, counts = self._splice_on_device(st)
             elif st.kind == "device_res":
                 self._wait(st.res)
                 res = self._d2h("res", st.res)
@@ -545,21 +526,63 @@ class BatchedDeidExecutor:
             # host_encode: jobs were queued at submit
             t1 = clk.now() if clk is not None else None
 
-            total = 0
+            total = escapes = host_packed = 0
             for j, i in enumerate(chunk):
-                payload, k, _ = self._job_result(st.jobs[j])
+                job = st.jobs[j]
+                if job is None:  # spliced on the device
+                    payload = memoryview(coded[j, : (int(counts[j, 0]) + 7) // 8])
+                    k, esc = int(ks[j]), int(counts[j, 1])
+                else:
+                    payload, k, esc = self._job_result(job)
+                    host_packed += 1
                 total += len(payload)
+                escapes += esc
                 out[i] = BatchOutput(
                     pixels=items[i][0],
                     payload=codec.pack_header(H, W, st.bits, sv, k, len(payload))
                     + payload,
                 )
-            sp.set(bytes_out=total)
+            sp.set(bytes_out=total, escapes=escapes,
+                   device_packed=len(chunk) - host_packed, host_packed=host_packed)
             if clk is not None:
                 sp.set(
                     queue_s=round(t0 - st.t_submit, 9),
                     wait_s=round(t1 - t0, 9),
                 )
+
+    def _splice_on_device(self, st: _Chunk):
+        """The device plan's Golomb-Rice splice: ``k`` per frame from the
+        copied row sums, then the device codes every frame into a payload
+        buffer of the frame's raw size, and only the buffer and the bit
+        totals cross to the host. A frame whose code outgrows the buffer
+        is packed on the host from copies of its planes, as a pack job.
+        Sets ``st.jobs`` (None for a frame the device coded) and returns
+        (payload buffer, ks, per-frame bit totals and escape counts)."""
+        from repro.kernels.jls import entropy
+
+        n, H, W = len(st.idxs), st.H, st.W
+        self._wait(st.rs, st.u)
+        rs = self._d2h("rs", st.rs)
+        # one k per padded frame: padding frames get k=0 and are never
+        # packed, but the kernels read a k for every frame
+        ks = np.zeros(st.u.shape[0], np.int32)
+        for j in range(n):
+            ks[j] = codec._rice_k_from_sum(int(rs[j].sum()), H * W)
+        lens_d, rem_d = entropy.rice_len_rem(st.u, ks, bh=self.bh, interpret=self.interpret)
+        capacity = H * W * (st.bits // 8)
+        payload_d, counts_d = entropy.rice_pack_device(
+            st.u, lens_d, rem_d, ks, capacity=capacity, interpret=self.interpret
+        )
+        self._wait(payload_d, counts_d)
+        counts = self._d2h("counts", counts_d)
+        payload = self._d2h("payload", payload_d)
+        over = [j for j in range(n) if (int(counts[j, 0]) + 7) // 8 > capacity]
+        plans = [codec.RicePlan(int(ks[j]), self._d2h("lens", lens_d[j]),
+                                self._d2h("rem", rem_d[j]), self._d2h("u", st.u[j]))
+                 for j in over]
+        jobs = dict(zip(over, self._submit_jobs([lambda p=p: _pack(p) for p in plans])))
+        st.jobs = [jobs.get(j) for j in range(n)]
+        return payload, ks, counts
 
     # ------------------------------------------------------------- detection
     def detect_row_hits(

@@ -14,7 +14,10 @@ Huffman entropy coding. We implement the same two-phase structure:
   computable on the accelerator by the ``kernels/jls`` entropy pre-pass —
   and a **pack** phase (:func:`rice_pack`) that prefix-sums the lengths
   into bit offsets and splices the variable-length codes into the final
-  bitstream with word-level scatter-OR writes. Only the splice is host work.
+  bitstream with word-level scatter-OR writes. The executor's kernel path
+  runs the same splice on the device (``kernels/jls/entropy``
+  ``rice_pack_device``), byte for byte; this one is the host's, and the
+  reference the device's is tested against.
 
 Round-trips are exact (lossless) — asserted by unit + property tests.
 """

@@ -20,6 +20,7 @@ TRACE_NAMES = {
     "fused": "_fused",                # fused/ops.py: scrub + JLS residuals
     "jls.prepass": "_prepass",        # jls/entropy.py: zigzag + row sums
     "jls.len_rem": "_len_rem",        # jls/entropy.py: Rice lengths + remainders
+    "jls.splice": "_splice",          # jls/entropy.py: Rice splice to payloads
     "jls.residuals": "_residuals",    # jls/ops.py: JLS residuals alone
     "textdetect": "_profiles",        # textdetect/ops.py: text-band profiles
     "scrub": "_scrub_padded",         # scrub/ops.py: rectangle blanking

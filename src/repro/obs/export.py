@@ -44,8 +44,10 @@ ALLOWED_ATTR_KEYS = frozenset({
     # timing facts
     "busy_s", "t_lease", "visibility",
     # device/host pipeline boundary timing (DESIGN.md §12): device->host
-    # copies, the pack pool's CPU time and each packed frame's escape count
+    # copies, the pack pool's CPU time, escape counts and the frames a
+    # chunk's device splice and host pack jobs coded
     "queue_s", "wait_s", "bytes", "array", "cpu_s", "escapes",
+    "device_packed", "host_packed",
     # outcome flags
     "ok", "deduped", "fenced", "crashed", "mode",
     # SLO / critical-path profile plane (DESIGN.md §13)

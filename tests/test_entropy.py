@@ -330,8 +330,8 @@ def _legacy_escapes(res):
 
 
 class TestDevicePlanExecutor:
-    """The executor's device-plan path (Pallas pre-pass in interpret mode,
-    then the host splice) against the bit-array oracle, escapes present."""
+    """The executor's device-plan path (Pallas pre-pass and splice in
+    interpret mode) against the bit-array oracle, escapes present."""
 
     def _run(self, items, host_workers=2, tracer=None):
         from repro.core.batch import BatchedDeidExecutor
@@ -361,14 +361,18 @@ class TestDevicePlanExecutor:
             np.testing.assert_array_equal(codec.decode(out.payload), out.pixels)
 
     def test_pack_jobs_carry_escape_counts(self, rng):
+        """Each chunk's ``kernel.entropy_code`` span counts the escapes of
+        the frames the device spliced (chunks of 4 and 1 frames)."""
         from repro.obs.trace import Tracer
         from repro.utils.timing import SimClock
 
         items = _spiked_items(rng)
         tr = Tracer(SimClock())
         self._run(items, tracer=tr)
-        got = [s.attrs["escapes"] for s in tr.spans("kernel.pack_job")]
-        assert got == [_legacy_escapes(r) for r in self._blanked_residuals(items)]
+        want = [_legacy_escapes(r) for r in self._blanked_residuals(items)]
+        got = [s.attrs["escapes"] for s in tr.spans("kernel.entropy_code")]
+        assert got == [sum(want[:4]), want[4]] and sum(want) > 0
+        assert tr.spans("kernel.pack_job") == []
 
     def test_trace_digest_invariant_to_pool_size(self, rng):
         from repro.obs.trace import Tracer
@@ -379,6 +383,136 @@ class TestDevicePlanExecutor:
         for host_workers in (0, 4):
             tr = Tracer(SimClock())
             self._run(items, host_workers=host_workers, tracer=tr)
-            assert len(tr.spans("kernel.pack_job")) == len(items)
+            spans = tr.spans("kernel.entropy_code")
+            assert sum(s.attrs["device_packed"] for s in spans) == len(items)
             digests.add(tr.digest())
         assert len(digests) == 1
+
+
+def _u_with_k(rng, k, n):
+    """Zigzag magnitudes that code at Rice parameter ``k`` with quotients
+    from 0 up, below 2^17 as the magnitudes of <= 16-bit planes are; three
+    at 2^17 - 1, escapes at every k up to 12."""
+    q = np.minimum(rng.geometric(0.35, size=n) - 1, (2**17 - 1) >> k)
+    u = (q << k) | rng.integers(0, 2**k, size=n)
+    u[rng.choice(n, size=3, replace=False)] = 2**17 - 1
+    return u
+
+
+def _escape_straddle(rng):
+    """Escapes among 1-bit codes (k=0): the first, at symbol 10, starts at
+    bit 10, so its 25-bit prefix (QMAX+1 ones and the stop bit) crosses
+    into the next 32-bit word; the others start where the codes before
+    them end, and the test checks that some prefix straddles a word."""
+    u = np.zeros((24, 40), np.int64)
+    flat = u.reshape(-1)
+    for i in (10, 20, 31, 140, 171):
+        flat[i] = 30 + i
+    flat[400:700:3] = rng.integers(24, 2**17, size=100)  # escape-heavy rows
+    return u, 0
+
+
+# Each case: (u (H, W), k). A padding frame of zeros rides with each, as in
+# a chunk padded to a power of two.
+_DEVICE_SPLICE_CASES = {
+    **{f"k{k}": (lambda k: lambda rng: (_u_with_k(rng, k, 24 * 40).reshape(24, 40), k))(k)
+       for k in range(18)},
+    "escape_prefixes_straddle_words": _escape_straddle,
+    "one_by_one": lambda rng: (np.array([[70000]]), 16),
+    "height_not_a_multiple_of_bh": lambda rng: (_u_with_k(rng, 3, 20 * 24).reshape(20, 24), 3),
+    "width_2048": lambda rng: (_u_with_k(rng, 5, 3 * 2048).reshape(3, 2048), 5),
+}
+
+
+class TestDeviceSplice:
+    """``entropy.rice_pack_device`` (Pallas in interpret mode on the CPU)
+    against ``codec.rice_pack`` of the same plan, byte for byte."""
+
+    @pytest.mark.parametrize("case", sorted(_DEVICE_SPLICE_CASES))
+    def test_payload_equals_rice_pack(self, rng, case):
+        from repro.kernels.jls import entropy
+
+        u, k = _DEVICE_SPLICE_CASES[case](rng)
+        H, W = u.shape
+        u_b = np.stack([u, np.zeros_like(u)]).astype(np.int32)
+        ks = np.array([k, 0], np.int32)
+        lens_d, rem_d = entropy.rice_len_rem(u_b, ks, bh=16)
+        capacity = 8 * H * W  # room for the frame's code (escapes, k up to 17)
+        payload, counts = entropy.rice_pack_device(u_b, lens_d, rem_d, ks, capacity=capacity)
+        payload, counts = np.asarray(payload), np.asarray(counts)
+        lens_np, rem_np = np.asarray(lens_d), np.asarray(rem_d)
+        for j in range(2):
+            want, escapes = codec.rice_pack(
+                codec.RicePlan(int(ks[j]), lens_np[j], rem_np[j], u_b[j].astype(np.uint64)))
+            nbytes = (int(counts[j, 0]) + 7) // 8
+            assert nbytes == len(want) and int(counts[j, 1]) == escapes
+            assert payload[j, :nbytes].tobytes() == want
+        if case == "escape_prefixes_straddle_words":
+            ends = np.cumsum(lens_np[0].ravel())
+            starts = ends - lens_np[0].ravel()
+            esc = lens_np[0].ravel() == _QMAX + 2 + 64
+            prefix_end = (starts + _QMAX + 1)[esc]  # the stop bit
+            assert (starts[esc] // 32 != prefix_end // 32).any()
+        elif case.startswith("k") and ((2**17 - 1) >> k) > _QMAX:
+            assert int(counts[0, 1]) > 0  # escapes wherever a magnitude can code one
+
+    def test_batched_payloads_decode(self, rng):
+        """The executor's device-spliced streams of a chunk of CT-like slices
+        and a 1x1 frame decode to the blanked pixels."""
+        from repro.core.batch import BatchedDeidExecutor
+
+        items = _spiked_items(rng, n=3) + [(np.array([[4000]], np.uint16), [])]
+        ex = BatchedDeidExecutor(max_batch=4, use_kernel=True, interpret=True, host_workers=0)
+        outs = ex.run([(px.copy(), rl) for px, rl in items], sv=1)
+        for out in outs:
+            np.testing.assert_array_equal(codec.decode(out.payload), out.pixels)
+
+    def test_frame_over_capacity_takes_the_host_pack(self, rng):
+        """A u8 noise frame codes past its raw size: the device leaves it to
+        a host pack job, and every payload still matches the encoder's."""
+        from repro.core.batch import BatchedDeidExecutor, blank_inplace
+        from repro.obs.trace import Tracer
+        from repro.utils.timing import SimClock
+
+        items = [(rng.integers(0, 256, size=(40, 56)).astype(np.uint8), [(0, 0, 8, 4)]),
+                 (rng.normal(128, 3, size=(40, 56)).clip(0, 255).astype(np.uint8), [])]
+        tr = Tracer(SimClock())
+        ex = BatchedDeidExecutor(max_batch=4, use_kernel=True, interpret=True, tracer=tr)
+        try:
+            outs = ex.run([(px.copy(), rl) for px, rl in items], sv=1)
+        finally:
+            ex.close()
+        for out, (px, rl) in zip(outs, items):
+            assert out.payload == codec.encode(blank_inplace(px.copy(), rl), 1)
+        assert len(outs[0].payload) > 40 * 56 + 20  # past the frame's raw size
+        (ec,) = tr.spans("kernel.entropy_code")
+        assert (ec.attrs["device_packed"], ec.attrs["host_packed"]) == (1, 1)
+        assert len(tr.spans("kernel.pack_job")) == 1
+        copies = [s.attrs["array"] for s in tr.spans("kernel.d2h")]
+        assert copies == ["rs", "counts", "payload", "lens", "rem", "u"]
+
+
+def test_device_splice_on_the_chip(rng):
+    """A chunk of 32 CT slices with a burned-in band (escapes) through the
+    compiled kernels on the chip, against ``codec.rice_pack``."""
+    import jax
+
+    from repro.kernels.jls import entropy
+
+    if jax.default_backend() != "tpu":
+        pytest.skip("compares the compiled splice on a TPU")
+
+    res = np.stack([_ct_frame(rng) for _ in range(32)])
+    u_d, rs_d = entropy.rice_prepass(res)
+    rs = np.asarray(rs_d)
+    ks = np.array([codec._rice_k_from_sum(int(r.sum()), res[0].size) for r in rs], np.int32)
+    lens_d, rem_d = entropy.rice_len_rem(u_d, ks)
+    payload, counts = entropy.rice_pack_device(u_d, lens_d, rem_d, ks, capacity=512 * 512 * 2)
+    payload, counts = np.asarray(payload), np.asarray(counts)
+    escapes = 0
+    for j in range(32):
+        want, esc = codec.rice_pack(codec.rice_plan(res[j]))
+        nbytes = (int(counts[j, 0]) + 7) // 8
+        assert payload[j, :nbytes].tobytes() == want and int(counts[j, 1]) == esc
+        escapes += esc
+    assert escapes > 0

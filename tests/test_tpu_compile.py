@@ -89,7 +89,21 @@ def _len_rem(rows, cols, dtype, spec):
     )
 
 
-KERNELS = {"fused_scrub_residuals": _fused, "rice_prepass": _prepass, "rice_len_rem": _len_rem}
+def _pack_device(rows, cols, dtype, spec):
+    # the payload buffer holds the frame's raw size, as the executor sizes it
+    capacity = rows * cols * jnp.dtype(dtype).itemsize
+    return (
+        lambda u, lens, rem, ks: entropy.rice_pack_device(
+            u, lens, rem, ks, capacity=capacity, interpret=False),
+        spec((BATCH, rows, cols), jnp.int32),
+        spec((BATCH, rows, cols), jnp.int32),
+        spec((BATCH, rows, cols), jnp.int32),
+        spec((BATCH,), jnp.int32),
+    )
+
+
+KERNELS = {"fused_scrub_residuals": _fused, "rice_prepass": _prepass, "rice_len_rem": _len_rem,
+           "rice_pack_device": _pack_device}
 
 
 @pytest.mark.parametrize("geometry", sorted(GEOMETRY))

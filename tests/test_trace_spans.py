@@ -66,7 +66,8 @@ class TestTracerRecord:
 
 class TestExecutorCollectSpans:
     """(a): under each ``kernel.entropy_code``, the chunk's device waits and
-    copies once each, and one ``kernel.pack_job`` per frame."""
+    copies once each; the device plan's frames are spliced on the device,
+    so no ``kernel.pack_job`` runs for them."""
 
     @pytest.mark.parametrize("host_workers", [0, 2])
     def test_device_plan_chunk(self, rng, host_workers):
@@ -79,19 +80,19 @@ class TestExecutorCollectSpans:
             kids = _children(tr, ec)
             names = [s.name for s in kids]
             n = ec.attrs["batch"]
-            assert names == (["kernel.device_wait", "kernel.d2h", "kernel.d2h",
-                              "kernel.device_wait", "kernel.d2h", "kernel.d2h"]
-                             + ["kernel.pack_job"] * n)
+            assert names == ["kernel.device_wait", "kernel.d2h",
+                             "kernel.device_wait", "kernel.d2h", "kernel.d2h"]
             copies = [s for s in kids if s.name == "kernel.d2h"]
-            assert [s.attrs["array"] for s in copies] == ["rs", "u", "lens", "rem"]
+            assert [s.attrs["array"] for s in copies] == ["rs", "counts", "payload"]
             n_pad = 4 if n == 4 else 1
-            px = n_pad * 64 * 128
-            # padded frames cross too: u, lens and rem are int32 planes
-            assert [s.attrs["bytes"] for s in copies[1:]] == [4 * px] * 3
+            # padded frames cross too: a bit total and an escape count each,
+            # and a payload buffer of the frame's raw size (u16 pixels)
+            assert [s.attrs["bytes"] for s in copies[1:]] == [8 * n_pad, 2 * n_pad * 64 * 128]
             assert copies[0].attrs["bytes"] > 0
+            assert (ec.attrs["device_packed"], ec.attrs["host_packed"]) == (n, 0)
             # under a simulated clock the trace must replay: no CPU seconds
             assert all("cpu_s" not in s.attrs for s in kids)
-        assert len(tr.spans("kernel.pack_job")) == len(items)
+        assert tr.spans("kernel.pack_job") == []
         assert tr.open_count == 0
 
     def test_device_residuals_chunk(self, rng):
@@ -113,8 +114,9 @@ class TestExecutorCollectSpans:
         assert len(tr.spans("kernel.device_wait")) == 1
 
     def test_wall_clock_pack_jobs_carry_cpu_seconds(self, rng):
+        # the device-residual path packs each frame on the pool
         tr = Tracer(WallClock())
-        _run(tr, _items(rng, n=3))
+        _run(tr, _items(rng, n=3), device_entropy=False)
         (ec,) = tr.spans("kernel.entropy_code")
         jobs = tr.spans("kernel.pack_job")
         assert len(jobs) == 3
@@ -214,10 +216,11 @@ def test_spans_land_in_the_profiler_trace(rng, tmp_path):
     starts = [off + ns * 1e-9 for ns in host.get("kernel.dispatch", [])]
     for s in spans:
         assert min(abs(t - s.t0) for t in starts) < 1e-3
-    # the pool threads' jobs and the collector's spans are there too
+    # the collector's spans are there too; the device spliced every frame,
+    # so no pool thread ran a pack job
     for name in ("kernel.entropy_code", "kernel.device_wait", "kernel.d2h"):
         assert len(host.get(name, [])) == len(tr.spans(name))
-    assert len(host.get("kernel.pack_job", [])) == 5
+    assert "kernel.pack_job" not in host and tr.spans("kernel.pack_job") == []
 
 
 def test_kernel_trace_names_match_the_jitted_wrappers():
@@ -236,6 +239,7 @@ def test_kernel_trace_names_match_the_jitted_wrappers():
         "fused": fused_ops._fused,
         "jls.prepass": entropy._prepass,
         "jls.len_rem": entropy._len_rem,
+        "jls.splice": entropy._splice,
         "jls.residuals": jls_ops._residuals,
         "textdetect": textdetect_ops._profiles,
         "scrub": scrub_ops._scrub_padded,
