@@ -218,7 +218,7 @@ def main_path(tmp: Path) -> dict:
 
     # (c) delivered datasets and manifests == the serial oracle
     t0 = time.perf_counter()
-    serial = DeidPipeline(detector_policy=policy, batched=False)
+    serial = DeidPipeline(detector_policy=policy)
     n_checked = 0
     for s in studies:
         req = build_request(pseudo, s.accession, s.mrn)

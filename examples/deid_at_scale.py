@@ -159,9 +159,9 @@ def main() -> None:
     # accessions are served without publishing or dispatching anything (§6)
     cohort = list(mrns)[: max(args.studies // 2, 1)]
     pub0 = broker.total_published
-    disp0 = pipeline.executor.stats.dispatches if pipeline.executor else 0
+    disp0 = pipeline.executor.stats.dispatches
     ticket = service.submit_cohort("IRB-70007", cohort, mrns)
-    disp1 = pipeline.executor.stats.dispatches if pipeline.executor else 0
+    disp1 = pipeline.executor.stats.dispatches
     print(f"\ncohort replay: {len(ticket.hits)} warm / {len(ticket.cold)} cold "
           f"/ {len(ticket.rejected)} rejected of {len(cohort)}; "
           f"+{broker.total_published - pub0} publishes, +{disp1 - disp0} dispatches")

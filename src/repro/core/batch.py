@@ -1,10 +1,8 @@
 """Shape-bucketed, pipelined batch executor for the de-id hot path
 (DESIGN.md §4, §12).
 
-The production pipeline used to push one SOP instance at a time through
-``ScrubStage.__call__`` — a device round-trip per image. A study is hundreds
-of same-shape slices, so the executor restores the batching the hardware
-wants:
+A study is hundreds of same-shape slices, so the executor runs them through
+the device in batches instead of one SOP instance at a time:
 
 * **bucket** — group instances by (H, W, dtype, rect-count bucket). Studies
   mix 512x512 CT with 2500x2048 DX; dispatches must be shape-uniform.
@@ -12,44 +10,42 @@ wants:
   ``max_batch``, itself normalized to a power of two) and its rect dim to
   the bucket's power-of-two, so the jit cache only ever sees a small,
   closed set of padded shapes.
-* **dispatch** — one fused scrub+JLS kernel call per chunk
-  (``kernels/fused``: blank + predictor residuals in a single HBM pass),
-  or the batched scrub kernel alone when recompression is off.
-* **pipeline** — ``run`` is split into submit/collect with up to
-  ``pipeline_depth`` chunks in flight: the device dispatch of chunk N+1 is
-  issued (jax dispatch is asynchronous) before the host entropy tail of
-  chunk N is drained, so device and host work overlap instead of
-  serializing. On the kernel path the device also runs the Golomb-Rice
-  coder (``kernels/jls/entropy``: zigzag + row sums, then per-symbol code
-  lengths + remainders, then the splice), and only each frame's payload
-  crosses back; the host picks ``k`` from the row sums in between.
-* **host tail** — per-instance pack/encode jobs (the host paths, and a
-  device-plan frame whose code outgrows its raw size) are embarrassingly
-  parallel and fan out across a small thread pool (numpy releases the GIL);
-  jobs are pure functions of per-instance arrays and are drained in
-  submission order, so payload bytes are identical for any pool size —
-  including the inline ``host_workers=0`` mode.
+* **pipeline** — ``run`` is split into submit/collect with two chunks in
+  flight: the device dispatch of chunk N+1 is issued (jax dispatch is
+  asynchronous) before chunk N is collected, so device work overlaps the
+  host's collect.
+* **routes** — each chunk takes one of three:
+
+  - ``device_plan`` (kernel backend, recompression on): one fused
+    scrub+residual kernel call (``kernels/fused``) feeds the Golomb-Rice
+    pre-pass (``kernels/jls/entropy``: zigzag + row sums). At collect the
+    host picks ``k`` per frame from the copied row sums, the device computes
+    code lengths + remainders and splices each frame's stream, and only the
+    payloads and bit totals cross back. A frame whose code outgrows its raw
+    size is packed on the host from copies of its planes.
+  - ``host_encode`` (CPU backend): blank + residuals on the host, and one
+    pack job per frame at collect.
+  - ``scrub_only`` (recompression off): the batched scrub kernel alone on a
+    kernel backend, or the host blank on CPU.
+
+  A host pack job is a pure function of one frame, called at collect in
+  chunk order; the executor holds no threads.
 * **spans** — under ``kernel.entropy_code`` the collect phase records where
   the time goes: ``kernel.device_wait`` (blocking on the chunk's device
   outputs), ``kernel.d2h`` (each device->host copy, with its ``bytes``) and
-  one ``kernel.pack_job`` per host-packed frame with its ``escapes`` count,
-  timed on the pool thread that ran it and entered by the collector in
-  chunk order (DESIGN.md §11). The span itself counts the chunk's
-  ``escapes`` and the frames each route coded (``device_packed``,
-  ``host_packed``).
+  one ``kernel.pack_job`` per host-packed frame with its ``escapes`` count
+  (DESIGN.md §11). The span itself counts the chunk's ``escapes`` and the
+  frames each route coded (``device_packed``, ``host_packed``).
 
-The executor owns dispatch statistics and a lazily created pack pool; one
-instance can serve every stage/pipeline combination and is safe to share
-across the (single-threaded) worker pool simulation.
+The executor owns only dispatch statistics; one instance can serve every
+stage/pipeline combination and is safe to share across the
+(single-threaded) worker pool simulation.
 """
 from __future__ import annotations
 
-import contextlib
 import math
-import os
 import time
 from collections import defaultdict, deque
-from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
@@ -61,6 +57,9 @@ from repro.obs.metrics import Gauge, StatsShim
 from repro.obs.trace import NULL_TRACER
 
 _CODEC_DTYPES = ("uint8", "uint16")
+# chunks in flight: chunk N+1's device work is queued before chunk N is
+# collected
+PIPELINE_DEPTH = 2
 
 
 def _pow2_floor(n: int) -> int:
@@ -174,7 +173,7 @@ class _Chunk:
 
     __slots__ = (
         "idxs", "H", "W", "dtype_name", "rb", "bits", "kind",
-        "res", "u", "rs", "scrubbed", "jobs", "t_submit",
+        "u", "rs", "scrubbed", "jobs", "t_submit",
     )
 
     def __init__(self, idxs, H, W, dtype_name, rb):
@@ -182,28 +181,23 @@ class _Chunk:
         self.H, self.W, self.dtype_name, self.rb = H, W, dtype_name, rb
         self.bits = np.dtype(dtype_name).itemsize * 8
         self.kind = "done"
-        self.res = self.u = self.rs = self.scrubbed = None
+        self.u = self.rs = self.scrubbed = None
         self.jobs: Optional[list] = None
         self.t_submit: Optional[float] = None
 
 
 class BatchedDeidExecutor:
     """Groups a study's instances into shape buckets and runs the fused
-    scrub+JLS kernel once per bucket chunk, pipelined against the host
-    entropy tail.
+    scrub+JLS kernel once per bucket chunk, pipelined against the collect
+    of the chunk before.
 
-    ``use_kernel=None`` auto-detects: the fused Pallas kernel on accelerator
-    backends, the host two-pass (``blank_inplace`` + ``codec.residuals``) on
-    CPU — interpret-mode Pallas is a correctness stand-in, not a fast path.
-    Bucketing/chunking (and the dispatch statistics) are identical either
-    way, so the batching architecture is exercised on every backend.
-
-    ``pipeline_depth`` is the max number of chunks in flight (1 disables
-    overlap — strict submit-then-collect). ``host_workers`` sizes the pack
-    pool (None auto-sizes, 0 runs pack jobs inline on the collect thread).
-    ``device_entropy`` gates the Pallas Rice plan pre-pass (None follows
-    ``use_kernel``). None of these change a single output byte — only where
-    and when the work runs.
+    ``use_kernel=None`` auto-detects: the fused Pallas kernel and the device
+    Golomb-Rice coder on accelerator backends, the host two-pass
+    (``blank_inplace`` + ``codec.residuals``) and host pack on CPU —
+    interpret-mode Pallas is a correctness stand-in, not a fast path. Both
+    give the same bytes, and bucketing/chunking (and the dispatch
+    statistics) are identical either way, so the batching architecture is
+    exercised on every backend.
     """
 
     def __init__(
@@ -213,9 +207,6 @@ class BatchedDeidExecutor:
         interpret: Optional[bool] = None,
         use_kernel: Optional[bool] = None,
         tracer=None,
-        host_workers: Optional[int] = None,
-        pipeline_depth: int = 2,
-        device_entropy: Optional[bool] = None,
         registry=None,
     ) -> None:
         if max_batch < 1:
@@ -227,14 +218,10 @@ class BatchedDeidExecutor:
         self.bh = bh
         self.interpret = interpret
         self.use_kernel = use_kernel
-        self.host_workers = host_workers
-        self.pipeline_depth = pipeline_depth
-        self.device_entropy = device_entropy
         self.stats = ExecutorStats(registry)
         # per-dispatch profiling spans (kernel.dispatch / kernel.entropy_code
         # / kernel.detect_dispatch) — the roofline measurement substrate
         self.tracer = tracer if tracer is not None else NULL_TRACER
-        self._pool: Optional[ThreadPoolExecutor] = None
 
     def _resolve_use_kernel(self) -> bool:
         if self.use_kernel is None:
@@ -243,73 +230,22 @@ class BatchedDeidExecutor:
             self.use_kernel = not on_cpu()
         return self.use_kernel
 
-    def _use_device_entropy(self, use_kernel: bool) -> bool:
-        if self.device_entropy is not None:
-            return bool(self.device_entropy) and use_kernel
-        return use_kernel
-
-    # ------------------------------------------------------------ pack pool
-    def _resolve_workers(self) -> int:
-        if self.host_workers is not None:
-            return max(0, int(self.host_workers))
-        return min(4, os.cpu_count() or 1)
-
-    def _ensure_pool(self) -> Optional[ThreadPoolExecutor]:
-        if self._resolve_workers() <= 0:
-            return None
-        if self._pool is None:
-            self._pool = ThreadPoolExecutor(
-                max_workers=self._resolve_workers(), thread_name_prefix="rice-pack"
-            )
-        return self._pool
-
     def close(self) -> None:
-        """Shut down the pack pool (idempotent; the executor stays usable —
-        the pool is recreated lazily on the next run)."""
-        if self._pool is not None:
-            self._pool.shutdown(wait=True)
-            self._pool = None
+        """Nothing to release: the executor holds no threads or buffers
+        between runs."""
 
-    def _submit_jobs(self, fns) -> list:
-        """Queue pure per-instance pack jobs; inline thunks when pool is off.
-        Job order == chunk order either way, so drain order (and therefore
-        every output byte) is independent of the pool size."""
-        pool = self._ensure_pool()
-        if self.tracer.enabled:
-            fns = [self._timed(fn) for fn in fns]
-        if pool is None:
-            return list(fns)  # evaluated lazily, in order, on collect
-        return [pool.submit(fn) for fn in fns]
-
-    def _timed(self, fn):
-        """``fn`` as a job that also returns when it ran on the tracer's
-        clock and, on the wall clock, the CPU seconds of the thread that ran
-        it (left out under a simulated clock, whose trace must replay)."""
-        tracer, wall = self.tracer, self.tracer.wall
-
-        def job():
+    def _pack_job(self, job) -> Tuple[bytes, int, int]:
+        """Run one frame's pack job as a ``kernel.pack_job`` span carrying
+        the frame's escape count and, on the wall clock, the CPU seconds it
+        took (left out under a simulated clock, whose trace must replay)."""
+        wall = self.tracer.enabled and self.tracer.wall
+        with self.tracer.span("kernel.pack_job") as sp:
             c0 = time.thread_time() if wall else 0.0
-            t0 = tracer.clock.now()
-            with tracer.annotation("kernel.pack_job") or contextlib.nullcontext():
-                result = fn()
-            t1 = tracer.clock.now()
-            return result, t0, t1, (time.thread_time() - c0 if wall else None)
-
-        return job
-
-    def _job_result(self, job):
-        """The job's (payload, k, escapes); with tracing on, its run is
-        entered as a closed ``kernel.pack_job`` span, with the frame's escape
-        count, under the open ``kernel.entropy_code``."""
-        result = job.result() if hasattr(job, "result") else job()
-        if not self.tracer.enabled:
-            return result
-        result, t0, t1, cpu_s = result
-        self.tracer.record(
-            "kernel.pack_job", t0, t1, escapes=result[2],
-            **({} if cpu_s is None else {"cpu_s": cpu_s}),
-        )
-        return result
+            payload, k, escapes = job()
+            sp.set(escapes=escapes)
+            if wall:
+                sp.set(cpu_s=time.thread_time() - c0)
+        return payload, k, escapes
 
     def _wait(self, *arrays) -> None:
         """Block until the chunk's device outputs are ready."""
@@ -360,42 +296,29 @@ class BatchedDeidExecutor:
         callers pass freshly copied arrays (``ScrubStage`` copies the dataset
         first). Returns outputs aligned with ``items``.
 
-        Submission and collection are pipelined: up to ``pipeline_depth``
-        chunks are dispatched (device work queued asynchronously) before the
-        oldest chunk's host entropy tail is drained, and chunks are always
-        collected in submission order. On any failure the in-flight pack
-        jobs are cancelled and the exception propagates — callers never see
-        a partially filled output list.
+        Submission and collection are pipelined: chunk N+1 is dispatched
+        (device work queued asynchronously) before chunk N is collected, and
+        chunks are always collected in submission order. A failure
+        propagates — callers never see a partially filled output list.
         """
         use_kernel = self._resolve_use_kernel()
         out: List[Optional[BatchOutput]] = [None] * len(items)
         buckets = self.bucket(items)
         self.stats.bucket_keys.update(buckets.keys())
         self.stats.dispatch_groups += len(buckets)
-        depth = max(1, int(self.pipeline_depth))
         inflight: deque = deque()
-        try:
-            for (H, W, dtype_name, rb), idxs in buckets.items():
-                for c0 in range(0, len(idxs), self.max_batch):
-                    chunk = idxs[c0 : c0 + self.max_batch]
-                    inflight.append(
-                        self._submit_chunk(
-                            items, chunk, H, W, dtype_name, rb, sv, recompress, use_kernel
-                        )
+        for (H, W, dtype_name, rb), idxs in buckets.items():
+            for c0 in range(0, len(idxs), self.max_batch):
+                chunk = idxs[c0 : c0 + self.max_batch]
+                inflight.append(
+                    self._submit_chunk(
+                        items, chunk, H, W, dtype_name, rb, sv, recompress, use_kernel
                     )
-                    while len(inflight) >= depth:
-                        self._collect_chunk(items, inflight.popleft(), sv, out)
-            while inflight:
-                self._collect_chunk(items, inflight.popleft(), sv, out)
-        except BaseException:
-            # crash containment: nothing submitted may leak — cancel queued
-            # pack jobs (running ones are pure and write no shared state)
-            # and let the exception escape with `out` discarded.
-            for st in inflight:
-                for job in st.jobs or ():
-                    if hasattr(job, "cancel"):
-                        job.cancel()
-            raise
+                )
+                if len(inflight) >= PIPELINE_DEPTH:
+                    self._collect_chunk(items, inflight.popleft(), sv, out)
+        while inflight:
+            self._collect_chunk(items, inflight.popleft(), sv, out)
         return out  # every index was bucketed exactly once
 
     # -- submit phase ------------------------------------------------------
@@ -428,6 +351,7 @@ class BatchedDeidExecutor:
         chunk; device values stay asynchronous until collect."""
         # import here so host-only core code never pulls jax at module import
         from repro.kernels.fused.ops import fused_scrub_residuals
+        from repro.kernels.jls import entropy
         from repro.kernels.scrub.ops import pack_rects, scrub_images
 
         chunk, H, W = st.idxs, st.H, st.W
@@ -444,16 +368,8 @@ class BatchedDeidExecutor:
             res = fused_scrub_residuals(
                 stack, rects, sv=sv, bits=st.bits, bh=self.bh, interpret=self.interpret
             )
-            if self._use_device_entropy(True):
-                from repro.kernels.jls import entropy
-
-                st.u, st.rs = entropy.rice_prepass(
-                    res, bh=self.bh, interpret=self.interpret
-                )
-                st.kind = "device_plan"
-            else:
-                st.res = res
-                st.kind = "device_res"
+            st.u, st.rs = entropy.rice_prepass(res, bh=self.bh, interpret=self.interpret)
+            st.kind = "device_plan"
             # host-side pixel blanking for the delivered object (banner
             # pixels only) happens at submit so collect is pure codec work
             for i in chunk:
@@ -463,21 +379,17 @@ class BatchedDeidExecutor:
             st.kind = "scrub_only"
 
     def _submit_host(self, items, st, sv, recompress) -> None:
-        """CPU path: blank + batched residuals now, queue the encode tail."""
+        """CPU path: blank now; the encode runs per frame at collect."""
         chunk = st.idxs
         for i in chunk:
             blank_inplace(items[i][0], items[i][1])
         if recompress:
             # per-instance residuals (not residuals_batch): one plane's int64
             # intermediates stay cache-resident, a whole chunk's do not
-            st.jobs = self._submit_jobs(
-                [
-                    lambda px=items[i][0]: _pack(
-                        codec.rice_plan(codec.residuals(px, sv))
-                    )
-                    for i in chunk
-                ]
-            )
+            st.jobs = [
+                lambda px=items[i][0]: _pack(codec.rice_plan(codec.residuals(px, sv)))
+                for i in chunk
+            ]
             st.kind = "host_encode"
         else:
             st.kind = "done"
@@ -506,24 +418,13 @@ class BatchedDeidExecutor:
         # in flight behind newer dispatches, wait_s = device syncs and
         # copies, the k loop and the device splice, up to the payload's
         # arrival)
-        # NB: pool size / pipeline depth are deliberately NOT span attrs —
-        # the trace digest must be identical for any host_workers setting
         with self.tracer.span(
             "kernel.entropy_code", batch=len(chunk), path=st.kind
         ) as sp:
             t0 = clk.now() if clk is not None else None
             if st.kind == "device_plan":
                 coded, ks, counts = self._splice_on_device(st)
-            elif st.kind == "device_res":
-                self._wait(st.res)
-                res = self._d2h("res", st.res)
-                st.jobs = self._submit_jobs(
-                    [
-                        lambda rj=res[j]: _pack(codec.rice_plan(rj))
-                        for j in range(len(chunk))
-                    ]
-                )
-            # host_encode: jobs were queued at submit
+            # host_encode: jobs were made at submit
             t1 = clk.now() if clk is not None else None
 
             total = escapes = host_packed = 0
@@ -533,7 +434,7 @@ class BatchedDeidExecutor:
                     payload = memoryview(coded[j, : (int(counts[j, 0]) + 7) // 8])
                     k, esc = int(ks[j]), int(counts[j, 1])
                 else:
-                    payload, k, esc = self._job_result(job)
+                    payload, k, esc = self._pack_job(job)
                     host_packed += 1
                 total += len(payload)
                 escapes += esc
@@ -580,7 +481,7 @@ class BatchedDeidExecutor:
         plans = [codec.RicePlan(int(ks[j]), self._d2h("lens", lens_d[j]),
                                 self._d2h("rem", rem_d[j]), self._d2h("u", st.u[j]))
                  for j in over]
-        jobs = dict(zip(over, self._submit_jobs([lambda p=p: _pack(p) for p in plans])))
+        jobs = {j: (lambda p=p: _pack(p)) for j, p in zip(over, plans)}
         st.jobs = [jobs.get(j) for j in range(n)]
         return payload, ks, counts
 

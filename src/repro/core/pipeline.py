@@ -88,7 +88,6 @@ class DeidPipeline:
         scrub_script: Optional[str] = None,
         blank_fn=None,
         recompress: bool = True,
-        batched: bool = True,
         lake: Optional["ResultLake"] = None,
         detector_policy=None,
         tracer=None,
@@ -114,11 +113,8 @@ class DeidPipeline:
         # audit ledger (repro.audit): one deid_execute record per run_study
         self.ledger = ledger if ledger is not None else NULL_LEDGER
         # shape-bucketed batch dispatch over each study's instances; the
-        # per-instance loop survives as process_study_serial (fallback/oracle)
-        self.executor: Optional[BatchedDeidExecutor] = (
-            BatchedDeidExecutor(tracer=self.tracer, registry=registry)
-            if batched else None
-        )
+        # per-instance loop survives as process_study_serial (the oracle)
+        self.executor = BatchedDeidExecutor(tracer=self.tracer, registry=registry)
         self.script_shas = {
             "filter": self.filter.sha,
             "anonymizer": self.anonymizer.sha,
@@ -206,13 +202,10 @@ class DeidPipeline:
         self, datasets: Sequence[DicomDataset], request: DeidRequest, worker_id: str
     ) -> List[Tuple[Optional[DicomDataset], ManifestEntry]]:
         """Run the three stages over a list of instances, returning aligned
-        (delivered-or-None, entry) pairs. Uses the shape-bucketed executor
-        when attached; falls back to the per-instance path otherwise.
+        (delivered-or-None, entry) pairs through the shape-bucketed executor.
 
         The filter and anonymize loops each run under one phase span; the
         scrub's ``kernel.*`` spans stay direct children of the study span."""
-        if self.executor is None:
-            return [self.process_instance(ds, request, worker_id) for ds in datasets]
         params = request.script_params()
         pairs: List[Optional[Tuple[Optional[DicomDataset], ManifestEntry]]] = [
             None

@@ -249,7 +249,7 @@ class ScrubStage:
         # hit profiles are handed to _resolve_rects (bit-identical to the
         # host oracle it would otherwise run per instance)
         hits_for: Dict[int, np.ndarray] = {}
-        if executor is not None and self.policy is not None and self.policy.enabled:
+        if self.policy is not None and self.policy.enabled:
             scan_idx: List[int] = []
             scan_items: List[Tuple[np.ndarray, float]] = []
             for i, ds in enumerate(datasets):
@@ -268,8 +268,7 @@ class ScrubStage:
                 slots[i] = (None, e)
                 continue
             batchable = (
-                executor is not None
-                and rect_semantics
+                rect_semantics
                 and executor.supports(ds.pixels, self.recompress)
                 # nothing to batch: no blanking and no recompression work
                 and (rects or self.recompress)

@@ -9,8 +9,7 @@ _DOC = """Multi-pod dry-run (deliverable e).
 For every (architecture x input-shape x mesh) cell: build abstract inputs
 (ShapeDtypeStruct, no allocation), jit with explicit shardings,
 ``.lower().compile()``, and record memory_analysis / cost_analysis /
-collective-bytes (parsed from the partitioned HLO) into a JSON the roofline
-harness (benchmarks/roofline.py) consumes.
+collective-bytes (parsed from the partitioned HLO) into a JSON report.
 
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-0.5b --shape train_4k --mesh single
   PYTHONPATH=src python -m repro.launch.dryrun --all            # every cell, both meshes

@@ -14,8 +14,6 @@ Design constraints, in order:
 3. **Single-threaded context.** The whole stack is step-driven off one event
    loop, so the active-span *stack* is the context: a span opened inside
    another parents to it automatically; roots name their trace explicitly.
-   Work timed on other threads is handed back and entered, closed, with
-   :meth:`Tracer.record` from the thread that owns the stack.
 4. **One clock with the device trace.** On a :class:`WallClock`, once JAX is
    imported, every span also enters a ``jax.profiler.TraceAnnotation`` of
    its name, so a profiler trace shows the program's spans on its host
@@ -135,8 +133,8 @@ class Tracer:
         self.finished: List[Span] = []
         self._stack: List[Span] = []
         self._seq = 0
-        # real time: spans mirror into the profiler's trace, and work timed
-        # on other threads may carry its CPU seconds
+        # real time: spans mirror into the profiler's trace, and a span may
+        # carry the CPU seconds of the work it timed
         self.wall = isinstance(clock, WallClock)
         self._annotation_cls = None  # jax.profiler.TraceAnnotation once found
         self._annotations: Dict[int, object] = {}  # open span seq -> annotation
@@ -147,37 +145,26 @@ class Tracer:
         """Open a span. Parents to the innermost open span; a root span with
         no explicit ``trace_id`` gets one minted from its own sequence number
         (deterministic)."""
-        span = self._new(name, trace_id, self.clock.now(), attrs)
+        self._seq += 1
+        seq = self._seq
+        parent = self._stack[-1] if self._stack else None
+        if trace_id is None:
+            trace_id = parent.trace_id if parent is not None else f"root{seq:08d}"
+        span = Span(
+            trace_id=trace_id,
+            span_id=f"s{seq:08d}",
+            parent_id=parent.span_id if parent is not None and parent.trace_id == trace_id else None,
+            name=name,
+            t0=self.clock.now(),
+            seq=seq,
+            attrs=dict(attrs),
+        )
         self._stack.append(span)
         ann = self.annotation(name)
         if ann is not None:
             ann.__enter__()
             self._annotations[span.seq] = ann
         return _ActiveSpan(self, span)
-
-    def record(self, name: str, t0: float, t1: float, **attrs) -> Span:
-        """Enter an already closed span, timed elsewhere (e.g. on a pool
-        thread), as a child of the innermost open span."""
-        span = self._new(name, None, t0, attrs)
-        span.t1 = t1
-        self.finished.append(span)
-        return span
-
-    def _new(self, name: str, trace_id: Optional[str], t0: float, attrs: dict) -> Span:
-        self._seq += 1
-        seq = self._seq
-        parent = self._stack[-1] if self._stack else None
-        if trace_id is None:
-            trace_id = parent.trace_id if parent is not None else f"root{seq:08d}"
-        return Span(
-            trace_id=trace_id,
-            span_id=f"s{seq:08d}",
-            parent_id=parent.span_id if parent is not None and parent.trace_id == trace_id else None,
-            name=name,
-            t0=t0,
-            seq=seq,
-            attrs=dict(attrs),
-        )
 
     def annotation(self, name: str):
         """A ``jax.profiler.TraceAnnotation`` named ``name`` when this tracer
@@ -259,9 +246,6 @@ class NullTracer:
         return _NOOP_SPAN
 
     def event(self, name: str, trace_id: Optional[str] = None, **attrs) -> None:
-        return None
-
-    def record(self, name: str, t0: float, t1: float, **attrs) -> None:
         return None
 
     @property

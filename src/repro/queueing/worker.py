@@ -164,16 +164,14 @@ class DeidWorker:
         )
         slowdown = injector.slowdown(self.worker_id, msg) if injector else 1.0
         work_seconds = (study.nbytes() / self.throughput) * slowdown
-        batched0 = self.pipeline.executor.stats.instances if self.pipeline.executor else 0
+        batched0 = self.pipeline.executor.stats.instances
         dstats = self.pipeline.scrub.detect_stats
         unknown0, druns0 = dstats.unknown_lookups, dstats.detector_runs
         with tracer.span("worker.deid", bytes_in=study.nbytes(), busy_s=work_seconds):
             result = self.pipeline.run_study(study, request, self.worker_id)
         outputs, manifest = result.delivered, result.manifest
-        batched_delta = 0
-        if self.pipeline.executor is not None:
-            batched_delta = self.pipeline.executor.stats.instances - batched0
-            self.batched_instances += batched_delta
+        batched_delta = self.pipeline.executor.stats.instances - batched0
+        self.batched_instances += batched_delta
         self._batched_delta = batched_delta  # provenance: batch-bucket fact
         # unknown-device lookups are a surfaced worker metric, never a silent
         # pass-through (the shared scrub stage counts; workers take deltas)
@@ -253,7 +251,7 @@ class DeidWorker:
                 etag=source_etag,
                 ruleset=digest,
                 detector_sha=getattr(policy, "fingerprint_identity", "") if policy else "",
-                kernel_path="batched" if self.pipeline.executor is not None else "serial",
+                kernel_path="batched",
                 batched=getattr(self, "_batched_delta", 0),
                 trace_id=trace_id_for(msg.key, msg.deliveries),
                 temp="cold",

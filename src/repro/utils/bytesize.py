@@ -1,4 +1,4 @@
-"""Byte-size formatting/parsing helpers used across benchmarks and reports."""
+"""Byte-size formatting/parsing helpers for reports and service logs."""
 from __future__ import annotations
 
 _UNITS = ["B", "KB", "MB", "GB", "TB", "PB"]

@@ -1,8 +1,8 @@
 """Wall-clock timing and a deterministic simulated clock.
 
 The broker/autoscaler/tracer layers accept any object satisfying the
-:class:`Clock` protocol (``now()`` + ``advance(dt)``); tests and benchmarks use
-:class:`SimClock` so queue/lease/scaling/trace behaviour is fully
+:class:`Clock` protocol (``now()`` + ``advance(dt)``); tests and the fleet
+simulator use :class:`SimClock` so queue/lease/scaling/trace behaviour is fully
 deterministic, while production wiring passes :class:`WallClock`.
 """
 from __future__ import annotations

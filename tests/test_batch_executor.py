@@ -108,10 +108,9 @@ class TestPipelineBatchedEqualsSerial:
         s = gen.gen_study(f"BE-{modality}-{recompress}", modality=modality, n_images=n, problem=problem)
         req = build_request(pseudo, s.accession, s.mrn)
         batched = DeidPipeline(recompress=recompress)
-        serial = DeidPipeline(recompress=recompress, batched=False)
-        assert batched.executor is not None and serial.executor is None
+        serial = DeidPipeline(recompress=recompress)
         out_b, man_b = batched.process_study(s, req, "w0")
-        out_s, man_s = serial.process_study(s, req, "w0")
+        out_s, man_s = serial.process_study_serial(s, req, "w0")
         assert man_b.to_json() == man_s.to_json()
         assert len(out_b) == len(out_s)
         for a, b in zip(out_b, out_s):
@@ -128,9 +127,9 @@ class TestPipelineBatchedEqualsSerial:
         req = build_request(pseudo, s.accession, s.mrn)
         batched = DeidPipeline()
         batched.executor.use_kernel = True
-        serial = DeidPipeline(batched=False)
+        serial = DeidPipeline()
         out_b, man_b = batched.process_study(s, req)
-        out_s, man_s = serial.process_study(s, req)
+        out_s, man_s = serial.process_study_serial(s, req)
         assert man_b.to_json() == man_s.to_json()
         for a, b in zip(out_b, out_s):
             np.testing.assert_array_equal(a.pixels, b.pixels)
@@ -174,9 +173,10 @@ class TestPipelineBatchedEqualsSerial:
         s.datasets[1].pixels = s.datasets[1].pixels[:-1]  # odd height -> explodes
         req = build_request(pseudo, s.accession, s.mrn)
         results = {}
-        for name, pipe in [("batched", DeidPipeline(blank_fn=exploding_blank)),
-                           ("serial", DeidPipeline(blank_fn=exploding_blank, batched=False))]:
-            outs, manifest = pipe.process_study(s, req)
+        pipe = DeidPipeline(blank_fn=exploding_blank)
+        for name, process in [("batched", pipe.process_study),
+                              ("serial", pipe.process_study_serial)]:
+            outs, manifest = process(s, req)
             outcomes = [e.outcome for e in manifest.entries]
             results[name] = (len(outs), outcomes)
             assert outcomes.count(Outcome.FAILED) == 1

@@ -104,7 +104,7 @@ class TestScrubStageFallback:
     def test_serial_and_batched_byte_identical(self, unknown_ct_study):
         pol = DetectorPolicy()
         batched = DeidPipeline(recompress=False, detector_policy=pol)
-        serial = DeidPipeline(recompress=False, detector_policy=pol, batched=False)
+        serial = DeidPipeline(recompress=False, detector_policy=pol)
         d1, m1 = batched.process_study(unknown_ct_study, _request())
         d2, m2 = serial.process_study_serial(unknown_ct_study, _request())
         assert [pickle.dumps(x) for x in d1] == [pickle.dumps(x) for x in d2]

@@ -333,16 +333,11 @@ class TestDevicePlanExecutor:
     """The executor's device-plan path (Pallas pre-pass and splice in
     interpret mode) against the bit-array oracle, escapes present."""
 
-    def _run(self, items, host_workers=2, tracer=None):
+    def _run(self, items, tracer=None):
         from repro.core.batch import BatchedDeidExecutor
 
-        ex = BatchedDeidExecutor(max_batch=4, use_kernel=True, interpret=True,
-                                 host_workers=host_workers, tracer=tracer)
-        try:
-            outs = ex.run([(px.copy(), rl) for px, rl in items], sv=1)
-        finally:
-            ex.close()
-        return outs
+        ex = BatchedDeidExecutor(max_batch=4, use_kernel=True, interpret=True, tracer=tracer)
+        return ex.run([(px.copy(), rl) for px, rl in items], sv=1)
 
     def _blanked_residuals(self, items):
         from repro.core.batch import blank_inplace
@@ -375,14 +370,15 @@ class TestDevicePlanExecutor:
         assert tr.spans("kernel.pack_job") == []
 
     def test_trace_digest_invariant_to_pool_size(self, rng):
+        """The same items give the same digest on two fresh executors."""
         from repro.obs.trace import Tracer
         from repro.utils.timing import SimClock
 
         items = _spiked_items(rng)
         digests = set()
-        for host_workers in (0, 4):
+        for _ in range(2):
             tr = Tracer(SimClock())
-            self._run(items, host_workers=host_workers, tracer=tr)
+            self._run(items, tracer=tr)
             spans = tr.spans("kernel.entropy_code")
             assert sum(s.attrs["device_packed"] for s in spans) == len(items)
             digests.add(tr.digest())
@@ -462,7 +458,7 @@ class TestDeviceSplice:
         from repro.core.batch import BatchedDeidExecutor
 
         items = _spiked_items(rng, n=3) + [(np.array([[4000]], np.uint16), [])]
-        ex = BatchedDeidExecutor(max_batch=4, use_kernel=True, interpret=True, host_workers=0)
+        ex = BatchedDeidExecutor(max_batch=4, use_kernel=True, interpret=True)
         outs = ex.run([(px.copy(), rl) for px, rl in items], sv=1)
         for out in outs:
             np.testing.assert_array_equal(codec.decode(out.payload), out.pixels)
@@ -478,10 +474,7 @@ class TestDeviceSplice:
                  (rng.normal(128, 3, size=(40, 56)).clip(0, 255).astype(np.uint8), [])]
         tr = Tracer(SimClock())
         ex = BatchedDeidExecutor(max_batch=4, use_kernel=True, interpret=True, tracer=tr)
-        try:
-            outs = ex.run([(px.copy(), rl) for px, rl in items], sv=1)
-        finally:
-            ex.close()
+        outs = ex.run([(px.copy(), rl) for px, rl in items], sv=1)
         for out, (px, rl) in zip(outs, items):
             assert out.payload == codec.encode(blank_inplace(px.copy(), rl), 1)
         assert len(outs[0].payload) > 40 * 56 + 20  # past the frame's raw size

@@ -518,11 +518,14 @@ class TestStaleByteFencing:
         )
         new_version.mrn = mrns["ACC0000"]
         store.put_study("ACC0000", new_version)
+        processed0 = sum(w.processed for w in workers)
         ticket = service.submit_cohort("IRB-9", list(mrns), mrns)
         # only the mutated accession went cold; the other is still warm
         assert service.planner.stats.stale_refreshes == 1
         assert "ACC0000" in ticket.cold or "ACC0000" in ticket.pending
         pool.drain()
+        # re-de-identification amplification 1: one study redone per mutation
+        assert sum(w.processed for w in workers) - processed0 == 1
         assert journal.supersessions == 1
         assert journal.etag_for("IRB-9/ACC0000") == store.study_etag("ACC0000")
         assert sum(w.evicted_stale for w in workers) == 1

@@ -1,10 +1,11 @@
-"""Pipelined executor properties (DESIGN.md §12): payload bytes and trace
-digests are invariant to host-pool size and pipeline depth, and a crash
+"""Pipelined executor properties (DESIGN.md §12): the kernel and host routes
+give the encoder's bytes, a trace replays to the same digest, and a crash
 mid-overlap never leaks a partial result batch."""
 import numpy as np
 import pytest
 
 from repro.core.batch import BatchedDeidExecutor
+from repro.core.scrub import numpy_blank
 from repro.dicom import codec
 from repro.obs.trace import Tracer
 from repro.utils.timing import SimClock
@@ -27,51 +28,49 @@ def _run(ex, items, sv=2):
 class TestOverlapDeterminism:
     @pytest.mark.parametrize("use_kernel", [False, True])
     def test_bytes_identical_across_pool_and_depth(self, rng, use_kernel):
+        """The kernel and host routes give the encoder's bytes of the
+        blanked frame."""
         items = _mk_items(rng)
-        ref = None
-        for host_workers in (0, 1, 3):
-            for depth in (1, 2, 4):
-                ex = BatchedDeidExecutor(
-                    max_batch=4,
-                    use_kernel=use_kernel,
-                    interpret=True if use_kernel else None,
-                    host_workers=host_workers,
-                    pipeline_depth=depth,
-                )
-                outs = _run(ex, items)
-                payloads = [o.payload for o in outs]
-                pixels = [o.pixels.tobytes() for o in outs]
-                if ref is None:
-                    ref = (payloads, pixels)
-                else:
-                    assert (payloads, pixels) == ref, (host_workers, depth)
-                ex.close()
+        ex = BatchedDeidExecutor(
+            max_batch=4, use_kernel=use_kernel, interpret=True if use_kernel else None
+        )
+        outs = _run(ex, items)
+        for out, (px, rl) in zip(outs, items):
+            want = numpy_blank(px, rl)
+            np.testing.assert_array_equal(out.pixels, want)
+            assert out.payload == codec.encode(want, 2)
 
     def test_trace_digest_invariant_to_pool_size(self, rng):
+        """The same items give the same digest on two fresh executors."""
         items = _mk_items(rng, n=7)
         digests = set()
-        for host_workers in (0, 3):
+        for _ in range(2):
             tracer = Tracer(SimClock())
-            ex = BatchedDeidExecutor(
-                max_batch=4,
-                use_kernel=False,
-                host_workers=host_workers,
-                pipeline_depth=2,
-                tracer=tracer,
-            )
+            ex = BatchedDeidExecutor(max_batch=4, use_kernel=False, tracer=tracer)
             _run(ex, items)
             assert tracer.spans("kernel.entropy_code")  # the tail was traced
-            # each frame's pack job is in the digest, timed on the pool
+            # each frame's pack job is in the digest
             assert len(tracer.spans("kernel.pack_job")) == len(items)
             digests.add(tracer.digest())
-            ex.close()
         assert len(digests) == 1
+
+    def test_next_chunk_is_dispatched_before_a_chunk_is_collected(self, rng):
+        """Two chunks in flight: chunk N+1's dispatch opens before chunk N's
+        collect, and the last two are collected after the last dispatch."""
+        tracer = Tracer(SimClock())
+        ex = BatchedDeidExecutor(max_batch=4, use_kernel=False, tracer=tracer)
+        _run(ex, _mk_items(rng, n=12))
+        order = [s.name for s in sorted(tracer.spans(), key=lambda s: s.seq)
+                 if s.name in ("kernel.dispatch", "kernel.entropy_code")]
+        n = order.count("kernel.dispatch")
+        assert n >= 3
+        assert order == (["kernel.dispatch"] * 2
+                         + ["kernel.entropy_code", "kernel.dispatch"] * (n - 2)
+                         + ["kernel.entropy_code"] * 2)
 
     def test_entropy_span_carries_boundary_timing(self, rng):
         tracer = Tracer(SimClock())
-        ex = BatchedDeidExecutor(
-            max_batch=4, use_kernel=False, host_workers=0, tracer=tracer
-        )
+        ex = BatchedDeidExecutor(max_batch=4, use_kernel=False, tracer=tracer)
         _run(ex, _mk_items(rng, n=4))
         spans = tracer.spans("kernel.entropy_code")
         assert spans
@@ -88,11 +87,9 @@ class TestOverlapDeterminism:
         s = gen.gen_study("OVL-US", modality="US", n_images=6)
         req = build_request(pseudo_overlap, s.accession, s.mrn)
         batched = DeidPipeline()
-        batched.executor.pipeline_depth = 3
-        batched.executor.host_workers = 2
-        serial = DeidPipeline(batched=False)
+        serial = DeidPipeline()
         out_b, man_b = batched.process_study(s, req, "w0")
-        out_s, man_s = serial.process_study(s, req, "w0")
+        out_s, man_s = serial.process_study_serial(s, req, "w0")
         assert man_b.to_json() == man_s.to_json()
         for a, b in zip(out_b, out_s):
             np.testing.assert_array_equal(a.pixels, b.pixels)
@@ -118,29 +115,26 @@ class TestCrashMidOverlap:
             return real_pack(plan)
 
         monkeypatch.setattr(codec, "rice_pack", flaky_pack)
-        ex = BatchedDeidExecutor(
-            max_batch=4, use_kernel=False, host_workers=3, pipeline_depth=3
-        )
+        ex = BatchedDeidExecutor(max_batch=4, use_kernel=False)
         with pytest.raises(RuntimeError, match="mid-overlap"):
             _run(ex, items)
         # nothing escaped: run() raised instead of returning a partial list,
-        # and the executor (and its pool) stays usable for the next study
+        # and the executor stays usable for the next study
         monkeypatch.setattr(codec, "rice_pack", real_pack)
         outs = _run(ex, items)
         assert all(o.payload is not None for o in outs)
-        ref = BatchedDeidExecutor(max_batch=4, use_kernel=False, host_workers=0)
+        ref = BatchedDeidExecutor(max_batch=4, use_kernel=False)
         for a, b in zip(outs, _run(ref, items)):
             assert a.payload == b.payload
-        ex.close()
 
     def test_inline_mode_crash_equivalent(self, rng, monkeypatch):
-        # same failure with no pool: identical exception surface
+        # a failure in the first chunk's pack surfaces unchanged
         items = _mk_items(rng, n=6)
 
         def boom(plan):
             raise RuntimeError("entropy coder died")
 
         monkeypatch.setattr(codec, "rice_pack", boom)
-        ex = BatchedDeidExecutor(max_batch=4, use_kernel=False, host_workers=0)
+        ex = BatchedDeidExecutor(max_batch=4, use_kernel=False)
         with pytest.raises(RuntimeError, match="died"):
             _run(ex, items)

@@ -96,6 +96,27 @@ class TestSloEngine:
         assert eng.state("cold_serve") == "ok"
         assert [a.action for a in eng.alerts] == ["fire", "resolve"]
 
+    def test_detection_delay_shrinks_with_regression_size(self):
+        """Every injected bad fraction pages, and a larger one pages no later
+        (multi-window burn alerting on the sim-scaled default rules; with
+        objective 0.9 the faintest catchable fraction is just over 0.2)."""
+        onset, delays = 600.0, []
+        for frac in (0.25, 0.4, 0.6, 1.0):
+            eng = SloEngine([SloSpec("cold_serve", objective=0.9, threshold=60.0,
+                                     rules=default_burn_rules(1.0 / 60.0))])
+            fired = None
+            for i in range(7200):
+                t = float(i)
+                # observation i is bad with density frac after the onset
+                bad = t >= onset and (i * 2654435761 % 1000) / 1000.0 < frac
+                eng.observe("cold_serve", t=t, value=90.0 if bad else 1.0)
+                if any(a.action == "fire" for a in eng.evaluate(t)):
+                    fired = t - onset
+                    break
+            assert fired is not None and fired >= 0, frac
+            delays.append(fired)
+        assert delays == sorted(delays, reverse=True), delays
+
     def test_observe_counts_batches(self):
         eng = _engine()
         eng.observe_counts("cold_serve", t=0.0, good=6, bad=4)
@@ -306,6 +327,13 @@ class TestCriticalPathProfiler:
         assert cells["retry"]["total_s"] == pytest.approx(10.0)
         assert cells["queue"]["total_s"] == pytest.approx(15.0)
         assert cells["deid"]["total_s"] == pytest.approx(3.0)
+
+    def test_fold_counts_every_serve(self):
+        tracer = Tracer(SimClock())
+        for i in range(40):
+            t = 10.0 * i
+            _scripted_serve(tracer, f"IRB/A{i}", t, t + 2.0, 1.0)
+        assert CriticalPathProfiler().fold(tracer.spans()) == 40
 
     def test_fold_is_idempotent(self):
         clock = SimClock()

@@ -1,6 +1,6 @@
 """Where the de-identification time goes (DESIGN.md §11): the executor's
 collect phase splits into device waits, device->host copies and per-frame
-pack jobs; the pipeline's own host work into per-study phases; and on the
+host pack jobs; the pipeline's own host work into per-study phases; and on the
 wall clock every span also lands in the JAX profiler's trace."""
 import sys
 import time
@@ -8,27 +8,25 @@ import time
 import numpy as np
 import pytest
 
-from repro.core.batch import BatchedDeidExecutor
+from repro.core.batch import BatchedDeidExecutor, blank_inplace
+from repro.dicom import codec
 from repro.obs.trace import NULL_TRACER, Tracer
 from repro.utils.timing import SimClock, WallClock
 
 PHASES = ("pipeline.lake_lookup", "pipeline.filter", "pipeline.anonymize", "pipeline.lake_put")
 
 
-def _items(rng, n=5, shape=(64, 128)):
+def _items(rng, n=5, shape=(64, 128), dtype=np.uint16, hi=4000):
     return [
-        (rng.integers(0, 4000, size=shape).astype(np.uint16), [(4, 4, 24, 8)])
+        (rng.integers(0, hi, size=shape).astype(dtype), [(4, 4, 24, 8)])
         for _ in range(n)
     ]
 
 
-def _run(tracer, items, host_workers=2, **kw):
-    ex = BatchedDeidExecutor(max_batch=4, use_kernel=True, interpret=True,
-                             host_workers=host_workers, tracer=tracer, **kw)
-    try:
-        return ex.run([(px.copy(), rl) for px, rl in items], sv=1)
-    finally:
-        ex.close()
+def _run(tracer, items, use_kernel=True):
+    ex = BatchedDeidExecutor(max_batch=4, use_kernel=use_kernel,
+                             interpret=True if use_kernel else None, tracer=tracer)
+    return ex.run([(px.copy(), rl) for px, rl in items], sv=1)
 
 
 def _children(tracer, parent):
@@ -36,24 +34,7 @@ def _children(tracer, parent):
 
 
 class TestTracerRecord:
-    def test_record_enters_a_closed_child_of_the_open_span(self):
-        tr = Tracer(SimClock())
-        with tr.span("outer") as h:
-            got = tr.record("inner", 1.0, 2.5, cpu_s=0.5)
-            assert tr.open_count == 1
-        assert got.parent_id == h.span.span_id and got.trace_id == h.span.trace_id
-        assert (got.t0, got.t1, got.attrs) == (1.0, 2.5, {"cpu_s": 0.5})
-        assert got.seq > h.span.seq
-        assert [s.name for s in tr.spans()] == ["inner", "outer"]
-
-    def test_record_without_an_open_span_is_a_root(self):
-        tr = Tracer(SimClock())
-        got = tr.record("alone", 0.0, 1.0)
-        assert got.parent_id is None and got.trace_id.startswith("root")
-
-    def test_null_tracer_records_nothing(self):
-        assert NULL_TRACER.record("x", 0.0, 1.0, cpu_s=1.0) is None
-        assert NULL_TRACER.spans() == []
+    """How the tracer records a span beside the profiler's trace."""
 
     def test_annotation_only_on_the_wall_clock_with_jax_imported(self, monkeypatch):
         import jax  # noqa: F401  (the rule keys on it being imported)
@@ -67,13 +48,15 @@ class TestTracerRecord:
 class TestExecutorCollectSpans:
     """(a): under each ``kernel.entropy_code``, the chunk's device waits and
     copies once each; the device plan's frames are spliced on the device,
-    so no ``kernel.pack_job`` runs for them."""
+    so no ``kernel.pack_job`` runs for them. The host route packs each
+    frame in a ``kernel.pack_job`` of its own."""
 
-    @pytest.mark.parametrize("host_workers", [0, 2])
-    def test_device_plan_chunk(self, rng, host_workers):
+    @pytest.mark.parametrize("dtype", [np.uint8, np.uint16])
+    def test_device_plan_chunk(self, rng, dtype):
         tr = Tracer(SimClock())
-        items = _items(rng, n=5)
-        _run(tr, items, host_workers=host_workers)
+        # noise that codes below the frame's raw size at either width
+        items = _items(rng, n=5, dtype=dtype, hi=16 if dtype == np.uint8 else 4000)
+        _run(tr, items)
         entropy = tr.spans("kernel.entropy_code")
         assert [s.attrs["batch"] for s in entropy] == [4, 1]
         for ec in entropy:
@@ -86,8 +69,10 @@ class TestExecutorCollectSpans:
             assert [s.attrs["array"] for s in copies] == ["rs", "counts", "payload"]
             n_pad = 4 if n == 4 else 1
             # padded frames cross too: a bit total and an escape count each,
-            # and a payload buffer of the frame's raw size (u16 pixels)
-            assert [s.attrs["bytes"] for s in copies[1:]] == [8 * n_pad, 2 * n_pad * 64 * 128]
+            # and a payload buffer of the frame's raw size
+            itemsize = np.dtype(dtype).itemsize
+            assert [s.attrs["bytes"] for s in copies[1:]] == [8 * n_pad,
+                                                              n_pad * 64 * 128 * itemsize]
             assert copies[0].attrs["bytes"] > 0
             assert (ec.attrs["device_packed"], ec.attrs["host_packed"]) == (n, 0)
             # under a simulated clock the trace must replay: no CPU seconds
@@ -95,14 +80,20 @@ class TestExecutorCollectSpans:
         assert tr.spans("kernel.pack_job") == []
         assert tr.open_count == 0
 
-    def test_device_residuals_chunk(self, rng):
+    def test_host_encode_chunk(self, rng):
         tr = Tracer(SimClock())
-        _run(tr, _items(rng, n=3), device_entropy=False)
+        items = _items(rng, n=3, hi=4)
+        items[1][0][8:12, ::9] = 60000  # spikes in a flat frame code as escapes
+        _run(tr, items, use_kernel=False)
         (ec,) = tr.spans("kernel.entropy_code")
-        names = [s.name for s in _children(tr, ec)]
-        assert names == ["kernel.device_wait", "kernel.d2h"] + ["kernel.pack_job"] * 3
-        (copy,) = tr.spans("kernel.d2h")
-        assert copy.attrs == {"array": "res", "bytes": 4 * 4 * 64 * 128}
+        kids = _children(tr, ec)
+        assert [s.name for s in kids] == ["kernel.pack_job"] * 3
+        want = [codec.rice_pack(codec.rice_plan(codec.residuals(
+            blank_inplace(px.copy(), rl), 1)))[1] for px, rl in items]
+        assert [s.attrs for s in kids] == [{"escapes": e} for e in want]
+        assert want[1] > 0 and ec.attrs["escapes"] == sum(want)
+        assert (ec.attrs["device_packed"], ec.attrs["host_packed"]) == (0, 3)
+        assert tr.spans("kernel.d2h") == []
 
     def test_scrub_only_copy_is_traced(self, rng):
         tr = Tracer(SimClock())
@@ -114,9 +105,9 @@ class TestExecutorCollectSpans:
         assert len(tr.spans("kernel.device_wait")) == 1
 
     def test_wall_clock_pack_jobs_carry_cpu_seconds(self, rng):
-        # the device-residual path packs each frame on the pool
+        # the host route packs each frame at collect
         tr = Tracer(WallClock())
-        _run(tr, _items(rng, n=3), device_entropy=False)
+        _run(tr, _items(rng, n=3), use_kernel=False)
         (ec,) = tr.spans("kernel.entropy_code")
         jobs = tr.spans("kernel.pack_job")
         assert len(jobs) == 3
@@ -154,7 +145,6 @@ class TestPipelinePhases:
         tr = Tracer(SimClock())
         pipe = DeidPipeline(tracer=tr, lake=lake)
         pipe.run_study(s, build_request(pseudo, s.accession, s.mrn))
-        pipe.executor.close()
         return tr, s
 
     def test_phases_with_a_result_lake(self, gen, pseudo):
@@ -217,7 +207,7 @@ def test_spans_land_in_the_profiler_trace(rng, tmp_path):
     for s in spans:
         assert min(abs(t - s.t0) for t in starts) < 1e-3
     # the collector's spans are there too; the device spliced every frame,
-    # so no pool thread ran a pack job
+    # so no pack job ran
     for name in ("kernel.entropy_code", "kernel.device_wait", "kernel.d2h"):
         assert len(host.get(name, [])) == len(tr.spans(name))
     assert "kernel.pack_job" not in host and tr.spans("kernel.pack_job") == []
